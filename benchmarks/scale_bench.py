@@ -4,8 +4,11 @@ and the persistent AOT compile cache.  Writes ``BENCH_scale.json``
 
 Three sections, one uniform row schema:
 
-* **frontier** — per ``ApspBackend``, the largest N whose APSP closure
-  fits a fixed memory budget AND per-probe time budget.  Every backend
+* **host-frontier** — per ``ApspBackend``, the largest N whose APSP
+  closure fits a fixed host-memory budget AND per-probe time budget.
+  These are HOST probes: each child is pinned to the CPU
+  (``JAX_PLATFORMS=cpu``) and measures its own peak RSS, so they say
+  nothing about the accelerator.  Every backend
   probes the SAME degree-16 random regular graph (dense backends densify
   it; ``ell-bf`` streams the padded-ELL tables through
   ``repro.kernels.ell.ell_bf_apsp_streamed`` and never materializes a
@@ -26,8 +29,12 @@ Three sections, one uniform row schema:
   build (coarsening is exact — same matrices, same program) while its
   lane is planned at the much smaller switch-only ``padded_n``.
 * **aot** — a compile-dominated certified workload run twice in fresh
-  subprocesses sharing one ``REPRO_AOT_CACHE_DIR``: the warm process
-  must report ZERO new XLA compiles and well under the cold wall.
+  subprocesses sharing one fixed cache directory
+  (``aotcache.cache_root() / "scale-bench-aot"``, emptied before the
+  cold run): the warm process must report ZERO new XLA compiles and well
+  under the cold wall.  The children run before this process touches
+  JAX — a chip belongs to one process, so a parent holding it would
+  starve them.
 
     PYTHONPATH=src python -m benchmarks.scale_bench [--smoke]
 """
@@ -36,16 +43,16 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 import numpy as np
 
 import repro
 from benchmarks.common import rows_to_csv, write_bench_json
-from repro.core import traffic
+from repro.core import aotcache, traffic
 from repro.core.engine import get_engine
 from repro.core.vl2 import VL2Spec, vl2_topology
 
@@ -105,21 +112,25 @@ print(json.dumps(out))
 """
 
 
-def _child_env() -> dict:
+def _child_env(host: bool = False) -> dict:
     # repro may be a namespace package (__file__ is None): use __path__
     src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [src] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    if host:
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
-def _run_child(src: str, argv: list[str], timeout: float) -> dict | None:
-    """Run a probe subprocess; None = failed/over-time (the probe's own
-    budget verdict is the caller's job)."""
+def _run_child(src: str, argv: list[str], timeout: float,
+               host: bool = False) -> dict | None:
+    """Run a probe subprocess (``host``: pinned to the CPU); None =
+    failed/over-time (the probe's own budget verdict is the caller's
+    job)."""
     try:
         out = subprocess.run([sys.executable, "-c", src, *argv],
-                             env=_child_env(), capture_output=True,
+                             env=_child_env(host), capture_output=True,
                              text=True, timeout=timeout)
     except subprocess.TimeoutExpired:
         return None
@@ -140,11 +151,13 @@ def _frontier_rows(grid, mem_gb, time_s) -> list[dict]:
     rows = []
     for backend in _BACKENDS:
         for n in grid:
-            res = _run_child(_PROBE_SRC, [str(n), backend], timeout=time_s)
+            res = _run_child(_PROBE_SRC, [str(n), backend], timeout=time_s,
+                             host=True)
             ok = (res is not None and res["mem_gb"] <= mem_gb
                   and res["wall_s"] <= time_s)
             rows.append(_row(
-                section="frontier", backend=backend, label=f"apsp-{n}",
+                section="host-frontier", backend=backend,
+                label=f"host-apsp-{n}",
                 n=n, ok=bool(ok),
                 wall_s=None if res is None else round(res["wall_s"], 3),
                 mem_gb=None if res is None else round(res["mem_gb"], 3),
@@ -194,9 +207,10 @@ def _coarsen_rows(spec: VL2Spec, iters: int) -> list[dict]:
 
 def _aot_rows(iters: int, timeout: float) -> tuple[list[dict], float | None]:
     rows = []
-    with tempfile.TemporaryDirectory(prefix="repro-aot-bench-") as d:
-        cold = _run_child(_AOT_SRC, [d, str(iters)], timeout=timeout)
-        warm = _run_child(_AOT_SRC, [d, str(iters)], timeout=timeout)
+    d = aotcache.cache_root() / "scale-bench-aot"
+    shutil.rmtree(d, ignore_errors=True)   # the first child must be cold
+    cold = _run_child(_AOT_SRC, [str(d), str(iters)], timeout=timeout)
+    warm = _run_child(_AOT_SRC, [str(d), str(iters)], timeout=timeout)
     ratio = None
     for label, res in (("cold", cold), ("warm", warm)):
         ok = res is not None
@@ -228,13 +242,13 @@ def bench(scale: str = "small") -> tuple[list[dict], dict]:
         grid = [256, 512, 768, 1024, 2048, 4096, 8192, 16384]
         mem_gb, time_s, iters = 1.5, 150.0, 60
         spec = VL2Spec(d_a=8, d_i=8, servers_per_tor=5)
-    rows = _frontier_rows(grid, mem_gb, time_s)
-    frontier = {b: max((r["n"] for r in rows if r["backend"] == b
+    # children first: this process touches JAX only in _coarsen_rows
+    a_rows, ratio = _aot_rows(iters, timeout=max(time_s, 120.0))
+    f_rows = _frontier_rows(grid, mem_gb, time_s)
+    frontier = {b: max((r["n"] for r in f_rows if r["backend"] == b
                         and r["ok"]), default=0) for b in _BACKENDS}
     c_rows, equal, last_plan = _coarsen_rows(spec, iters)
-    rows += c_rows
-    a_rows, ratio = _aot_rows(iters, timeout=max(time_s, 120.0))
-    rows += a_rows
+    rows = f_rows + c_rows + a_rows
     extra = {"mem_budget_gb": mem_gb, "time_budget_s": time_s,
              "frontier": frontier, "coarsen_equal": bool(equal),
              "warm_over_cold": ratio,
@@ -262,9 +276,9 @@ def main() -> None:
     dt = time.time() - t0
     rows_to_csv(rows)
     fr = extra["frontier"]
-    head = (f"ell-bf frontier N={fr['ell-bf']} vs blocked-fw "
+    head = (f"host ell-bf frontier N={fr['ell-bf']} vs blocked-fw "
             f"N={fr['blocked-fw']} vs squaring N={fr['squaring']} "
-            f"under {extra['mem_budget_gb']}GB")
+            f"under {extra['mem_budget_gb']}GB host RSS")
     if extra["warm_over_cold"] is not None:
         head += f"; warm start {100 * extra['warm_over_cold']:.0f}% of cold"
     path = write_bench_json("scale", rows, headline=head, wall_s=dt,

@@ -7,18 +7,29 @@ This module serializes compiled executables to disk so a warm process
 skips XLA entirely:
 
 * ``AotCache(dir).call(jitfn, tag, args, static_kw)`` — look up the
-  executable keyed by (jax version, backend, device kind/count, tag,
-  arg shapes/dtypes, static kwargs).  On a hit the serialized executable
-  is deserialized and invoked; on a miss the function is lowered +
-  compiled ahead-of-time, serialized to the cache directory, then
-  invoked.  ANY failure (stale jax, incompatible device, corrupt blob)
-  falls back to the plain jitted call — the cache can only make things
-  faster, never wrong.
+  executable keyed by (jax version, backend, device kind/count, a digest
+  of this package's source, tag, arg shapes/dtypes, static kwargs), so
+  an entry compiled from other code never matches.  On a hit the
+  serialized executable is deserialized and invoked; on a miss the
+  function is lowered + compiled ahead-of-time, serialized to the cache
+  directory, then invoked.  A stale or corrupt entry is dropped and
+  recompiled, and a blob that cannot be written is skipped; both count
+  as ``errors``.  A program that fails to lower or compile raises — the
+  cache never swaps a kernel the compiler refused for some other path.
+  Each entry
+  records the compiled program's custom-call targets; ``last`` holds
+  those of the most recent call (``"tpu_custom_call"`` = a Mosaic
+  kernel ran).
 * ``resolve(knob)`` — map an engine-level knob (None / bool / directory
-  path) to an ``AotCache`` or ``None``.  ``None`` defers to the
-  ``REPRO_AOT_CACHE`` env var (truthy enables; ``REPRO_AOT_CACHE_DIR``
-  overrides the location), so CI can flip the cache on without touching
-  call sites.
+  path / ``AotCache``) to an ``AotCache`` or ``None``.  ``None`` defers
+  to the ``REPRO_AOT_CACHE`` env var (truthy enables;
+  ``REPRO_AOT_CACHE_DIR`` overrides the location), so CI can flip the
+  cache on without touching call sites.
+* ``cache_root()`` / ``enable_jax_cache()`` — where compiled programs
+  live: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself),
+  else ``<checkout>/.jax_cache``.  The AOT blobs sit in its
+  ``repro-aot`` subdirectory, so nothing lands outside the checkout
+  unless that variable says so.
 * module-level counters (``stats()``) — ``compiles`` / ``hits`` /
   ``misses`` / ``errors``, surfaced through
   ``plan.compile_cache_sizes()`` so benchmark drivers can assert the
@@ -30,16 +41,21 @@ on ``sharding is None``.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import pickle
+import re
 import warnings
 from pathlib import Path
 from typing import Any, Mapping, Sequence
 
 import jax
 
-__all__ = ["AotCache", "resolve", "default_dir", "stats", "reset_stats"]
+__all__ = ["AotCache", "resolve", "default_dir", "cache_root",
+           "enable_jax_cache", "stats", "reset_stats"]
+
+_CHECKOUT = Path(__file__).resolve().parents[3]
 
 _COUNTERS = {"compiles": 0, "hits": 0, "misses": 0, "errors": 0}
 _WARNED: set[str] = set()
@@ -55,19 +71,39 @@ def reset_stats() -> None:
         _COUNTERS[k] = 0
 
 
+def cache_root() -> Path:
+    """Directory of every compile cache: ``$JAX_COMPILATION_CACHE_DIR``
+    when set, else ``<checkout>/.jax_cache`` (a fixed path, since the
+    path is part of what a cache entry is found under)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return Path(env).expanduser() if env else _CHECKOUT / ".jax_cache"
+
+
+def enable_jax_cache() -> Path:
+    """Turn on JAX's persistent compilation cache at ``cache_root()``.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
+    no other directory is set here."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(cache_root()))
+    return cache_root()
+
+
 def default_dir() -> Path:
     env = os.environ.get("REPRO_AOT_CACHE_DIR")
     if env:
         return Path(env).expanduser()
-    return Path("~/.cache/repro/aot").expanduser()
+    return cache_root() / "repro-aot"
 
 
-def resolve(knob: bool | str | os.PathLike | None) -> "AotCache | None":
+def resolve(knob: "bool | str | os.PathLike | AotCache | None"
+            ) -> "AotCache | None":
     """Map the engine's ``aot_cache`` knob to a cache instance.
 
     ``None`` -> env-controlled (``REPRO_AOT_CACHE`` truthy enables),
     ``False`` -> off, ``True`` -> default directory, str/path -> that
-    directory."""
+    directory, an ``AotCache`` -> itself."""
+    if isinstance(knob, AotCache):
+        return knob
     if knob is None:
         env = os.environ.get("REPRO_AOT_CACHE", "").strip().lower()
         if env in ("", "0", "false", "off", "no"):
@@ -86,6 +122,17 @@ def _warn_once(key: str, msg: str) -> None:
         warnings.warn(msg, RuntimeWarning, stacklevel=3)
 
 
+@functools.lru_cache(maxsize=None)
+def source_digest() -> str:
+    """sha256 over every ``.py`` file of the ``repro`` package."""
+    root = Path(__file__).resolve().parents[1]
+    h = hashlib.sha256()
+    for p in sorted(root.rglob("*.py")):
+        h.update(p.relative_to(root).as_posix().encode())
+        h.update(p.read_bytes())
+    return h.hexdigest()
+
+
 def _abstract(x: Any) -> tuple:
     a = jax.api_util.shaped_abstractify(x)
     return (tuple(a.shape), str(a.dtype))
@@ -97,6 +144,7 @@ class AotCache:
     def __init__(self, directory: os.PathLike | str):
         self.dir = Path(directory)
         self.dir.mkdir(parents=True, exist_ok=True)
+        self.last: dict[str, Any] = {}   # meta of the most recent call
 
     # -- keying ---------------------------------------------------------
     def _key(self, tag: Sequence[str], args: Sequence[Any],
@@ -107,6 +155,7 @@ class AotCache:
             jax.default_backend(),
             devs[0].device_kind if devs else "none",
             len(devs),
+            source_digest(),
             tuple(tag),
             tuple(_abstract(a) for a in args),
             tuple(sorted((k, repr(v)) for k, v in static_kw.items())),
@@ -123,59 +172,45 @@ class AotCache:
 
         Hit: deserialize the stored executable and invoke it on ``args``.
         Miss: ``jitfn.lower(...).compile()``, serialize, store, invoke.
-        Any error: warn once and fall back to the plain jitted call."""
-        try:
-            from jax.experimental import serialize_executable as se
-        except Exception:  # pragma: no cover - jax always ships it today
-            _warn_once("import", "aotcache: serialize_executable "
-                       "unavailable; AOT cache disabled")
-            _COUNTERS["errors"] += 1
-            return jitfn(*args, **static_kw)
+        Lowering and compile errors propagate; cache-side failures (an
+        unreadable entry, an unwritable blob) warn once and count."""
+        from jax.experimental import serialize_executable as se
 
-        try:
-            key = self._key(tag, args, static_kw)
-            path = self._path(key)
-        except Exception as e:
-            _COUNTERS["errors"] += 1
-            _warn_once("key", f"aotcache: keying failed ({e!r}); "
-                       "falling back to jit")
-            return jitfn(*args, **static_kw)
-
+        path = self._path(self._key(tag, args, static_kw))
         if path.exists():
             try:
                 blob = pickle.loads(path.read_bytes())
                 compiled = se.deserialize_and_load(
                     blob["payload"], blob["in_tree"], blob["out_tree"])
                 out = compiled(*args)
-                _COUNTERS["hits"] += 1
-                return out
             except Exception as e:
                 _COUNTERS["errors"] += 1
-                _warn_once(f"load:{key}",
+                _warn_once(f"load:{path.stem}",
                            f"aotcache: stale/corrupt entry {path.name} "
                            f"({e!r}); recompiling")
-                try:
-                    path.unlink()
-                except OSError:
-                    pass
+                path.unlink(missing_ok=True)
+            else:
+                _COUNTERS["hits"] += 1
+                self.last = blob["meta"]
+                return out
 
         _COUNTERS["misses"] += 1
+        compiled = jitfn.lower(*args, **static_kw).compile()
+        _COUNTERS["compiles"] += 1
+        self.last = {"tag": tuple(tag), "custom_calls": sorted(set(
+            re.findall(r'custom_call_target="([^"]+)"', compiled.as_text())))}
         try:
-            compiled = jitfn.lower(*args, **static_kw).compile()
             payload, in_tree, out_tree = se.serialize(compiled)
-            _COUNTERS["compiles"] += 1
             tmp = path.with_suffix(f".tmp{os.getpid()}")
             tmp.write_bytes(pickle.dumps(
                 {"payload": payload, "in_tree": in_tree,
-                 "out_tree": out_tree, "meta": {"tag": tuple(tag)}}))
+                 "out_tree": out_tree, "meta": self.last}))
             os.replace(tmp, path)
-            return compiled(*args)
         except Exception as e:
             _COUNTERS["errors"] += 1
-            _warn_once(f"compile:{'/'.join(map(str, tag))}",
-                       f"aotcache: AOT path failed ({e!r}); "
-                       "falling back to jit")
-            return jitfn(*args, **static_kw)
+            _warn_once(f"store:{'/'.join(map(str, tag))}",
+                       f"aotcache: could not store {path.name} ({e!r})")
+        return compiled(*args)
 
     def entries(self) -> list[str]:
         return sorted(p.stem for p in self.dir.glob("*.aot"))

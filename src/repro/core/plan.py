@@ -301,7 +301,10 @@ class BatchPlan:
             return None
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
+        # an Auto axis: jax.make_mesh defaults to Explicit axes, whose
+        # sharding-in-types cannot batch the solvers' lax.cond under vmap
         mesh = jax.make_mesh((self.devices,), ("batch",),
+                             axis_types=(jax.sharding.AxisType.Auto,),
                              devices=jax.local_devices()[:self.devices])
         return NamedSharding(mesh, P("batch"))
 

@@ -37,8 +37,11 @@ Flavors (mirroring ``repro.kernels.fw``):
   ``"ell-bf"`` registry backend runs (jnp tiles off-TPU, the Pallas
   round on TPU or with explicit ``interpret=True``).
 * ``ell_relax_round_pallas`` — ONE Jacobi relaxation round as a Pallas
-  grid over target tiles, returning the new carry plus per-tile
-  convergence flags.  Same fixed point as the Gauss-Seidel sweep.
+  grid over (target tile, source slab) blocks, returning the new carry
+  plus per-tile convergence flags.  The carry stays in HBM and each
+  predecessor row slab is gathered by DMA (the ``idx`` table is
+  scalar-prefetched into SMEM), so VMEM use does not grow with N.  Same
+  fixed point as the Gauss-Seidel sweep.
 * ``ell_bf_apsp_streamed`` — the frontier path: host-streamed source
   blocks.  Each block's ``(N, S)`` transposed carry converges
   independently (its own early exit) and lands in one preallocated host
@@ -53,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.minplus import resolve_interpret
 
@@ -62,6 +66,8 @@ __all__ = ["ell_bf_apsp", "ell_bf_apsp_streamed", "ell_relax_round_pallas",
 _INF = 1.0e18      # == repro.core.apsp._INF (no circular import; test-pinned)
 DEFAULT_TILE = 1024    # target rows per relaxation tile (CPU sweet spot)
 DEFAULT_BLOCK = 1024   # source columns per streamed block
+_SLAB = 1024           # sources per DMA'd row slab: one (8, 128) f32 tile
+_GATHER_BYTES = 4 << 20   # VMEM for one Pallas tile's gathered row slabs
 
 
 def _check_tables(idx: jax.Array, wgt: jax.Array) -> tuple[int, int]:
@@ -100,35 +106,97 @@ def _relax_tiles_jnp(m, idx, wgt, *, tile: int):
     return jax.lax.fori_loop(0, nt, relax_tile, (m, jnp.bool_(False)))
 
 
-def _relax_round_kernel(m_ref, idx_ref, wgt_ref, o_ref, c_ref):
-    m = m_ref[...]
-    it = idx_ref[...]
-    wt = wgt_ref[...]
-    t = it.shape[0]
-    mt = jax.lax.dynamic_slice_in_dim(m, pl.program_id(0) * t, t, axis=0)
+def _pallas_tile(d_max: int) -> int:
+    """Target rows per Pallas grid step: the largest power of two in
+    [8, 128] whose gathered row slabs (``d_max`` per row) fit
+    ``_GATHER_BYTES`` of VMEM.  Independent of N."""
+    tile = 128
+    while tile > 8 and d_max * tile * _SLAB * 4 > _GATHER_BYTES:
+        tile //= 2
+    return tile
 
-    def slot(j, acc):
-        return jnp.minimum(acc, jnp.take(m, it[:, j], axis=0) + wt[:, j, None])
 
-    new = jax.lax.fori_loop(0, it.shape[1], slot, mt)
-    o_ref[...] = new
-    c_ref[...] = jnp.any(new < mt).reshape(1)
+def _relax_round_kernel(idx_ref, wgt_ref, m_hbm, mt_ref, o_ref, buf, sem, *,
+                        tile: int, d_max: int):
+    """One (target tile, source slab) block of a Jacobi round.
+
+    ``idx_ref``/``wgt_ref`` are the flattened ELL tables in SMEM (scalar
+    prefetch); ``m_hbm`` is the whole pre-round carry, shaped (N, S/128,
+    128) and left in HBM; ``mt_ref`` is this tile's own rows.  Every
+    predecessor row slab ``m[idx[t, j], slab]`` — one (8, 128) tile — is
+    DMA'd into ``buf[j, r]``, so VMEM holds ``d_max * tile`` slabs
+    whatever N is."""
+    base = pl.program_id(0) * (tile * d_max)
+    slab = pl.multiple_of(pl.program_id(1) * (_SLAB // 128), _SLAB // 128)
+
+    def copy(r, j, k):
+        return pltpu.make_async_copy(
+            m_hbm.at[k, pl.ds(slab, _SLAB // 128)], buf.at[j, r], sem)
+
+    def start(r, c):
+        for j in range(d_max):
+            copy(r, j, idx_ref[base + r * d_max + j]).start()
+        return c
+
+    def wait(r, c):
+        # one semaphore counts every copy's bytes: a slab may be read only
+        # once ALL copies have landed
+        for j in range(d_max):
+            copy(r, j, 0).wait()
+        return c
+
+    def relax(r, c):
+        acc = mt_ref[r]
+        for j in range(d_max):
+            acc = jnp.minimum(acc, buf[j, r] + wgt_ref[base + r * d_max + j])
+        o_ref[r] = acc
+        return c
+
+    jax.lax.fori_loop(0, tile, start, 0)
+    jax.lax.fori_loop(0, tile, wait, 0)
+    jax.lax.fori_loop(0, tile, relax, 0)
+
+
+def _relax_round(m, idx, wgt, *, tile: int, interpret: bool | None):
+    """One Jacobi round on an aligned carry: N a multiple of ``tile``,
+    S a multiple of ``_SLAB``."""
+    n, d_max = idx.shape
+    s = m.shape[1]
+    m3 = m.astype(jnp.float32).reshape(n, s // 128, 128)
+    block = pl.BlockSpec((tile, _SLAB // 128, 128),
+                         lambda i, j, *_: (i, j, 0))
+    out = pl.pallas_call(
+        functools.partial(_relax_round_kernel, tile=tile, d_max=d_max),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tile, s // _SLAB),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), block],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((d_max, tile, _SLAB // 128, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct(m3.shape, jnp.float32),
+        interpret=resolve_interpret(interpret))(
+            idx.astype(jnp.int32).reshape(-1),
+            wgt.astype(jnp.float32).reshape(-1), m3, m3)
+    return out.reshape(n, s)
 
 
 def ell_relax_round_pallas(m: jax.Array, idx: jax.Array, wgt: jax.Array, *,
-                           tile: int = 256,
+                           tile: int = 64,
                            interpret: bool | None = None):
-    """One Jacobi relaxation round as a Pallas grid over target tiles.
+    """One Jacobi relaxation round as a Pallas grid over (target tile,
+    source slab) blocks.
 
-    Every tile reads the full pre-round carry (the grid is unordered, so
+    Every block reads the full pre-round carry (the grid is unordered, so
     tiles cannot see each other's updates within a round — unlike the
     sequential jnp sweep; both converge to the same closure).  Returns
     ``(new_m, changed[nt])`` where ``changed[i]`` is tile ``i``'s
     convergence flag — a tile that reports False has reached its fixed
     point.  ``tile`` must divide N here (the jnp flavor clamps instead);
-    the whole carry sits in one block, so on real TPU hardware N x S
-    must fit VMEM — CPU containers run the jnp flavor, and tests drive
-    this path in interpret mode.
+    the source axis is padded to whole slabs internally.  The carry stays
+    in HBM and predecessor rows are gathered by DMA, so VMEM use is
+    ``d_max * tile`` slabs of ``_SLAB`` sources at any N.
     """
     n, d_max = _check_tables(idx, wgt)
     if m.shape[0] != n:
@@ -136,35 +204,43 @@ def ell_relax_round_pallas(m: jax.Array, idx: jax.Array, wgt: jax.Array, *,
     if n % tile:
         raise ValueError(f"ell_relax_round_pallas: n={n} must be a multiple "
                          f"of tile={tile}")
-    nt = n // tile
     s = m.shape[1]
-    out_m, changed = pl.pallas_call(
-        _relax_round_kernel,
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((n, s), lambda i: (0, 0)),
-                  pl.BlockSpec((tile, d_max), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, d_max), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((tile, s), lambda i: (i, 0)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n, s), jnp.float32),
-                   jax.ShapeDtypeStruct((nt,), jnp.bool_)],
-        interpret=resolve_interpret(interpret))(
-            m.astype(jnp.float32), idx, wgt.astype(jnp.float32))
-    return out_m, changed
+    m = m.astype(jnp.float32)
+    mp = jnp.pad(m, ((0, 0), (0, (-s) % _SLAB)), constant_values=_INF)
+    out = _relax_round(mp, idx, wgt, tile=tile, interpret=interpret)[:, :s]
+    changed = (out != m).reshape(n // tile, tile * s).any(axis=1)
+    return out, changed
 
 
 def _bf_fixpoint(idx, wgt, m0, *, tile: int, max_rounds: int,
                  use_pallas: bool, interpret: bool | None):
     """Relax a transposed carry ``m0[t, s]`` to the shortest-path fixed
     point.  Traceable (no jit/donation here) so ``repro.core.apsp`` can
-    inline it under the solvers' jit/vmap.  Returns (m, rounds)."""
+    inline it under the solvers' jit/vmap.  Returns (m, rounds).
+
+    The Pallas flavor pads the carry once to whole (``_pallas_tile``
+    rows, ``_SLAB`` sources) blocks: padded targets list only themselves
+    at ``_INF`` and padded sources start at ``_INF``, so neither ever
+    changes nor reaches a real entry."""
+    n, s = m0.shape
+    m0 = m0.astype(jnp.float32)
+    if use_pallas:
+        d_max = idx.shape[1]
+        ptile = _pallas_tile(d_max)
+        pad_n, pad_s = (-n) % ptile, (-s) % _SLAB
+        own = jnp.broadcast_to(jnp.arange(n, n + pad_n)[:, None],
+                               (pad_n, d_max))
+        idx = jnp.concatenate([idx.astype(jnp.int32),
+                               own.astype(jnp.int32)])
+        wgt = jnp.pad(wgt.astype(jnp.float32), ((0, pad_n), (0, 0)),
+                      constant_values=_INF)
+        m0 = jnp.pad(m0, ((0, pad_n), (0, pad_s)), constant_values=_INF)
 
     def round_(carry):
         m, _, rounds = carry
         if use_pallas:
-            m, flags = ell_relax_round_pallas(m, idx, wgt, tile=tile,
-                                              interpret=interpret)
-            ch = jnp.any(flags)
+            new = _relax_round(m, idx, wgt, tile=ptile, interpret=interpret)
+            m, ch = new, jnp.any(new != m)
         else:
             m, ch = _relax_tiles_jnp(m, idx, wgt, tile=tile)
         return m, ch, rounds + 1
@@ -173,9 +249,8 @@ def _bf_fixpoint(idx, wgt, m0, *, tile: int, max_rounds: int,
         return carry[1] & (carry[2] < max_rounds)
 
     m, _, rounds = jax.lax.while_loop(
-        cond, round_, (m0.astype(jnp.float32), jnp.bool_(True),
-                       jnp.int32(0)))
-    return m, rounds
+        cond, round_, (m0, jnp.bool_(True), jnp.int32(0)))
+    return m[:n, :s], rounds
 
 
 def _full_init(idx, wgt):

@@ -22,7 +22,10 @@ Backend flavors (see ``repro.core.apsp`` for the registry):
 
 * ``fw_apsp_pallas`` — the tiled kernel path (compiled on TPU; the Pallas
   interpreter is the explicit-``interpret=True`` escape hatch used by the
-  property tests);
+  property tests).  Inside a kernel every slice is static or a masked
+  reduction: the pivot closure extracts row/column ``k`` of the tile with
+  a ``min`` over an iota mask (exact, so distances are bit-identical to
+  the jnp flavor), and the panel updates share ``minplus.minplus_acc``;
 * ``fw_apsp_jnp`` — portable ``lax.fori_loop`` Floyd-Warshall (one
   ``O(N^2)`` relaxation per node).  Same algorithm family and identical
   distances; this is what CPU containers run, where the interpreter
@@ -36,15 +39,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.minplus import resolve_interpret
+from repro.kernels.minplus import minplus_acc, resolve_interpret
 
 __all__ = ["fw_apsp_pallas", "fw_apsp_jnp", "fw_tile_closure"]
 
 
 def fw_tile_closure(d: jax.Array) -> jax.Array:
     """In-tile Floyd-Warshall closure of a square (t, t) block: t sequential
-    relaxations ``d = min(d, d[:, k] + d[k, :])``.  Used for the pivot phase
-    and as the single-tile fast path."""
+    relaxations ``d = min(d, d[:, k] + d[k, :])``.  The whole-matrix body
+    of ``fw_apsp_jnp``; the Pallas pivot phase runs the masked twin
+    below."""
     t = d.shape[0]
 
     def body(k, dd):
@@ -55,36 +59,37 @@ def fw_tile_closure(d: jax.Array) -> jax.Array:
     return jax.lax.fori_loop(0, t, body, d)
 
 
-def _minplus_acc(acc: jax.Array, a: jax.Array, b: jax.Array,
-                 chunk: int) -> jax.Array:
-    """min(acc, A (min,+) B) with the k axis processed in small chunks so the
-    3-D broadcast stays under VMEM limits (same scheme as the minplus
-    kernel)."""
-    t = a.shape[1]
+def _tile_closure_masked(d: jax.Array) -> jax.Array:
+    """``fw_tile_closure`` as Mosaic lowers it: row and column ``k`` come
+    from a ``min`` over an iota mask instead of a dynamic slice of the
+    loaded tile (``min(x, inf) == x``, so every relaxation is the same
+    float operation as the jnp flavor's)."""
+    t = d.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
 
-    def body(i, o):
-        a_c = jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, i * chunk, chunk, axis=0)
-        return jnp.minimum(o, jnp.min(a_c[:, :, None] + b_c[None, :, :],
-                                      axis=1))
+    def body(k, dd):
+        row = jnp.min(jnp.where(rows == k, dd, jnp.inf), axis=0, keepdims=True)
+        col = jnp.min(jnp.where(cols == k, dd, jnp.inf), axis=1, keepdims=True)
+        return jnp.minimum(dd, col + row)
 
-    return jax.lax.fori_loop(0, t // chunk, body, acc)
+    return jax.lax.fori_loop(0, t, body, d)
 
 
 def _pivot_kernel(d_ref, o_ref):
-    o_ref[...] = fw_tile_closure(d_ref[...])
+    o_ref[...] = _tile_closure_masked(d_ref[...])
 
 
 def _row_panel_kernel(p_ref, r_ref, o_ref, *, chunk: int):
-    o_ref[...] = _minplus_acc(r_ref[...], p_ref[...], r_ref[...], chunk)
+    o_ref[...] = minplus_acc(r_ref[...], p_ref[...], r_ref[...], chunk)
 
 
 def _col_panel_kernel(c_ref, p_ref, o_ref, *, chunk: int):
-    o_ref[...] = _minplus_acc(c_ref[...], c_ref[...], p_ref[...], chunk)
+    o_ref[...] = minplus_acc(c_ref[...], c_ref[...], p_ref[...], chunk)
 
 
 def _outer_kernel(d_ref, c_ref, r_ref, o_ref, *, chunk: int):
-    o_ref[...] = _minplus_acc(d_ref[...], c_ref[...], r_ref[...], chunk)
+    o_ref[...] = minplus_acc(d_ref[...], c_ref[...], r_ref[...], chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("t", "chunk", "interpret"))
@@ -107,8 +112,6 @@ def fw_apsp_pallas(w: jax.Array, *, t: int = 128, chunk: int = 8,
     interpret = resolve_interpret(interpret)
     nb = n // t
     d = w.astype(jnp.float32)
-    if nb == 1:
-        return fw_tile_closure(d)
 
     row_call = pl.pallas_call(
         functools.partial(_row_panel_kernel, chunk=chunk),
@@ -139,6 +142,8 @@ def fw_apsp_pallas(w: jax.Array, *, t: int = 128, chunk: int = 8,
         _pivot_kernel,
         out_shape=jax.ShapeDtypeStruct((t, t), jnp.float32),
         interpret=interpret)
+    if nb == 1:
+        return pivot_call(d)
 
     # one traced pivot step, rolled over kk with lax.fori_loop: a Python
     # loop here unrolls nb pivot/row/col/outer call groups into the trace

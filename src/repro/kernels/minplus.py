@@ -8,11 +8,14 @@ APSP under evolving edge lengths every iteration).
 
 TPU adaptation: the tropical semiring has no MXU support, so the kernel is
 blocked exactly like a matmul (HBM -> VMEM tiles, 128-aligned so the VPU
-lanes are fully used) but accumulates with elementwise add + min-reduce on
-the VPU.  The k-dimension is the innermost grid axis; the output block lives
-in VMEM across the k-loop and is min-accumulated in place.  Within a block,
-k is processed in small chunks so the 3-D broadcast (bm, chunk, bn) stays
-well under VMEM limits.
+lanes are fully used) but accumulates with elementwise add + min on the
+VPU.  The k-dimension is the innermost grid axis; the output block lives
+in VMEM across the k-loop and is min-accumulated in place.  Within a
+block, k is unrolled statically: step k broadcasts column ``A[:, k]``
+across lanes and row ``B[k, :]`` across sublanes, so every slice has a
+static offset (Mosaic cannot lower a dynamic slice on the lane axis, nor
+``dynamic_slice`` on a loaded value).  ``min`` is exact, so the order in
+which k is visited never changes a bit of the result.
 """
 from __future__ import annotations
 
@@ -22,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["minplus_matmul_pallas", "resolve_interpret"]
+__all__ = ["minplus_matmul_pallas", "minplus_acc", "resolve_interpret"]
 
 _NEG_INF_SAFE = 3.0e38   # "+inf" stand-in that survives adds (python float)
 
@@ -35,23 +38,28 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return interpret
 
 
-def _minplus_kernel(a_ref, b_ref, o_ref, *, bk: int, chunk: int):
+def minplus_acc(acc: jax.Array, a: jax.Array, b: jax.Array,
+                chunk: int) -> jax.Array:
+    """``min(acc, A (min,+) B)`` for in-kernel values ``a`` (m, K) and
+    ``b`` (K, n).  K is walked in ``chunk``-wide slabs, each unrolled with
+    static offsets (shared by the blocked Floyd-Warshall kernels)."""
+    k = a.shape[1]
+    for c0 in range(0, k, chunk):
+        a_c = a[:, c0:c0 + chunk]
+        b_c = b[c0:c0 + chunk, :]
+        for kk in range(a_c.shape[1]):
+            acc = jnp.minimum(acc, a_c[:, kk:kk + 1] + b_c[kk:kk + 1, :])
+    return acc
+
+
+def _minplus_kernel(a_ref, b_ref, o_ref, *, chunk: int):
     """One (bm, bn) output tile; min-accumulate over the k grid axis."""
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, _NEG_INF_SAFE)
 
-    a = a_ref[...]          # (bm, bk)
-    b = b_ref[...]          # (bk, bn)
-
-    def body(i, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, i * chunk, chunk, axis=0)
-        cand = jnp.min(a_c[:, :, None] + b_c[None, :, :], axis=1)
-        return jnp.minimum(acc, cand)
-
-    o_ref[...] = jax.lax.fori_loop(0, bk // chunk, body, o_ref[...])
+    o_ref[...] = minplus_acc(o_ref[...], a_ref[...], b_ref[...], chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "chunk",
@@ -84,7 +92,7 @@ def minplus_matmul_pallas(a: jax.Array, b: jax.Array, *,
 
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
-        functools.partial(_minplus_kernel, bk=bk, chunk=chunk),
+        functools.partial(_minplus_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

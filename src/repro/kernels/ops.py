@@ -36,14 +36,14 @@ def _pad_to(x: jax.Array, m0: int, m1: int, val: float) -> jax.Array:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def minplus_matmul(a: jax.Array, b: jax.Array, block: int = 128,
                    interpret: bool | None = None) -> jax.Array:
-    """C = A (min,+) B with padding to block multiples.  Differentiable:
-    the VJP routes cotangents through the argmin edges (ties split evenly),
-    which is exactly the shortest-path-DAG subgradient the MCF solver needs.
-    ``interpret=None`` auto-detects from the backend (compiled on TPU)."""
-    m, k = a.shape
+    """C = A (min,+) B with padding to block multiples (``INF`` rows and
+    columns, which never win a min), so every size runs the kernel.
+    Differentiable: the VJP routes cotangents through the argmin edges
+    (ties split evenly), which is exactly the shortest-path-DAG
+    subgradient the MCF solver needs.  ``interpret=None`` auto-detects
+    from the backend (compiled on TPU)."""
+    m, _ = a.shape
     _, n = b.shape
-    if min(m, k, n) < block:      # tiny instances: reference is faster
-        return _ref.minplus_matmul_ref(a, b)
     ap = _pad_to(a.astype(jnp.float32), block, block, INF)
     bp = _pad_to(b.astype(jnp.float32), block, block, INF)
     out = _minplus.minplus_matmul_pallas(ap, bp, bm=block, bn=block,

@@ -1,6 +1,7 @@
 """Persistent AOT compile cache (``repro.core.aotcache``): hits serve
-serialized executables with zero new XLA compiles, every failure mode
-falls back to plain JIT, and results are identical either way."""
+serialized executables with zero new XLA compiles, a bad entry is
+recompiled, a program that cannot be lowered raises, and results are
+identical either way."""
 import numpy as np
 import pytest
 
@@ -62,6 +63,20 @@ def test_different_shapes_get_different_entries(tmp_path):
     assert len(eng._aot.entries()) == 2
 
 
+def test_other_source_never_hits(tmp_path, monkeypatch):
+    """A cache that outlives a code change (a persistent
+    ``JAX_COMPILATION_CACHE_DIR``) must not serve the old program."""
+    t, dem = _instance()
+    get_engine("dual", iters=20, aot_cache=str(tmp_path)).solve_batch(
+        [t], [dem])
+    monkeypatch.setattr(aotcache, "source_digest", lambda: "edited")
+    get_engine("dual", iters=20, aot_cache=str(tmp_path)).solve_batch(
+        [t], [dem])
+    assert aotcache.stats() == {"compiles": 2, "hits": 0, "misses": 2,
+                                "errors": 0}
+    assert len(list(tmp_path.glob("*.aot"))) == 2
+
+
 def test_corrupt_entry_falls_back_and_heals(tmp_path):
     t, dem = _instance()
     eng = get_engine("dual", iters=50, aot_cache=str(tmp_path))
@@ -78,8 +93,8 @@ def test_corrupt_entry_falls_back_and_heals(tmp_path):
 
 
 def test_solver_level_fallback_on_unloadable_function(tmp_path):
-    """aot.call on something that cannot be lowered still returns the
-    plain call's result (warn-once, counted as an error)."""
+    """aot.call on something that cannot be lowered raises instead of
+    running some other path: a refused kernel must never be hidden."""
     cache = aotcache.AotCache(tmp_path)
     calls = []
 
@@ -87,10 +102,37 @@ def test_solver_level_fallback_on_unloadable_function(tmp_path):
         calls.append(x)
         return x * k
 
-    with pytest.warns(RuntimeWarning, match="falling back to jit"):
-        out = cache.call(plain, ("test",), (3,), {"k": 2})
-    assert out == 6 and calls == [3]
-    assert aotcache.stats()["errors"] == 1
+    with pytest.raises(AttributeError, match="lower"):
+        cache.call(plain, ("test",), (3,), {"k": 2})
+    assert calls == []
+    assert aotcache.stats() == {"compiles": 0, "hits": 0, "misses": 1,
+                                "errors": 0}
+    assert cache.entries() == []
+
+
+def test_entries_record_custom_calls(tmp_path):
+    """Each entry records its program's custom-call targets, on the miss
+    and again when a fresh instance serves the hit."""
+    t, dem = _instance()
+    cache = aotcache.AotCache(tmp_path)
+    get_engine("dual", iters=20, aot_cache=cache).solve_batch([t], [dem])
+    assert cache.last["tag"] == ("dual", "donated")
+    assert "tpu_custom_call" not in cache.last["custom_calls"]
+    warm = aotcache.AotCache(tmp_path)
+    get_engine("dual", iters=20, aot_cache=warm).solve_batch([t], [dem])
+    assert aotcache.stats()["hits"] == 1
+    assert warm.last == cache.last
+
+
+def test_default_dirs_stay_in_checkout_or_env(monkeypatch, tmp_path):
+    monkeypatch.delenv("REPRO_AOT_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    checkout = aotcache._CHECKOUT
+    assert aotcache.cache_root() == checkout / ".jax_cache"
+    assert aotcache.default_dir().is_relative_to(checkout)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert aotcache.cache_root() == tmp_path
+    assert aotcache.default_dir().is_relative_to(tmp_path)
 
 
 def test_resolve_knob_and_env(tmp_path, monkeypatch):

@@ -226,8 +226,8 @@ def test_scale_artifact_schema(tmp_path):
     assert scale_bench.SCALE_ROW_KEYS == SCALE_ROW_KEYS
     assert scale_bench.SCALE_EXTRA_KEYS == SCALE_EXTRA_KEYS
     row = dict.fromkeys(scale_bench._ROW_ORDER)
-    row.update(figure="scale", section="frontier", backend="ell-bf",
-               label="apsp-16384", n=16384, ok=True, wall_s=60.0,
+    row.update(figure="scale", section="host-frontier", backend="ell-bf",
+               label="host-apsp-16384", n=16384, ok=True, wall_s=60.0,
                mem_gb=1.34, peak_rss_mb=1340.0, d_max=16, rounds=4)
     extra = {"mem_budget_gb": 1.5, "time_budget_s": 150.0,
              "frontier": {"squaring": 512, "blocked-fw": 4096,

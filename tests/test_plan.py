@@ -8,8 +8,15 @@ dedicated matrix entry with::
         python -m pytest tests/test_plan.py -q
 
 In the plain tier-1 run (one CPU device) those tests skip and the
-single-device planning/chunking tests still execute.
+single-device planning/chunking tests still execute, as does one sharded
+solve in a child process with four virtual devices.
 """
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import numpy as np
 import pytest
@@ -176,6 +183,38 @@ def test_sharded_empty_and_single_instance():
     got = DualEngine(iters=150, devices=8).solve_batch(topos, dems)
     ref = mcf.solve_dual(topos[0], dems[0], iters=150)
     assert got[0].throughput == pytest.approx(ref.throughput_ub, rel=1e-4)
+
+
+SHARDED_SCRIPT = textwrap.dedent("""
+    import json, sys
+    import numpy as np
+    sys.path.insert(0, sys.argv[1])
+    from test_plan import _instances
+    from repro.core.engine import CertifiedEngine
+    topos, dems = _instances([12, 16, 16])
+    out = {}
+    for ndev in (1, 4):
+        eng = CertifiedEngine(iters=60, devices=ndev)
+        out[ndev] = [(r.meta["lb"], r.meta["ub"])
+                     for r in eng.solve_batch(topos, dems)]
+    print(json.dumps({"same": out[1] == out[4], "devices": len(out)}))
+""")
+
+
+def test_sharded_plan_on_four_host_devices():
+    """The sharded path in the plain one-device run: a child process with
+    four virtual CPU devices solves certified brackets (whose backward
+    batches a ``lax.cond``) on four devices and on one, bit-identically."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([os.path.join(here, "..", "src"),
+                                           os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", SHARDED_SCRIPT, here],
+                         env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert json.loads(out.stdout.strip().splitlines()[-1]) == {
+        "same": True, "devices": 2}
 
 
 def test_bucket_size_reexport_consistency():

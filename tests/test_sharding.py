@@ -102,8 +102,6 @@ SPMD_SCRIPT = textwrap.dedent("""
 """)
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="installed jax predates jax.sharding.AxisType")
 def test_spmd_train_step_matches_unsharded():
     env = dict(os.environ,
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
