@@ -55,8 +55,6 @@ import jax
 __all__ = ["AotCache", "resolve", "default_dir", "cache_root",
            "enable_jax_cache", "stats", "reset_stats"]
 
-_CHECKOUT = Path(__file__).resolve().parents[3]
-
 _COUNTERS = {"compiles": 0, "hits": 0, "misses": 0, "errors": 0}
 _WARNED: set[str] = set()
 
@@ -71,12 +69,24 @@ def reset_stats() -> None:
         _COUNTERS[k] = 0
 
 
+def _checkout() -> Path:
+    """The source checkout this package runs from (``<checkout>/src/repro``).
+    An installed copy has none, and must be told where caches go."""
+    src = Path(__file__).resolve().parents[2]
+    if src.name != "src":
+        raise RuntimeError(
+            f"repro runs from {src.parent}, not a source checkout's src/; "
+            f"set JAX_COMPILATION_CACHE_DIR to say where compiled programs "
+            f"are cached")
+    return src.parent
+
+
 def cache_root() -> Path:
     """Directory of every compile cache: ``$JAX_COMPILATION_CACHE_DIR``
     when set, else ``<checkout>/.jax_cache`` (a fixed path, since the
     path is part of what a cache entry is found under)."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    return Path(env).expanduser() if env else _CHECKOUT / ".jax_cache"
+    return Path(env).expanduser() if env else _checkout() / ".jax_cache"
 
 
 def enable_jax_cache() -> Path:

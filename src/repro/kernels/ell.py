@@ -34,14 +34,13 @@ rounds with a per-round convergence flag for early exit.
 Flavors (mirroring ``repro.kernels.fw``):
 
 * ``ell_bf_apsp`` — full (N, N) closure in one jitted program; what the
-  ``"ell-bf"`` registry backend runs (jnp tiles off-TPU, the Pallas
-  round on TPU or with explicit ``interpret=True``).
-* ``ell_relax_round_pallas`` — ONE Jacobi relaxation round as a Pallas
-  grid over (target tile, source slab) blocks, returning the new carry
-  plus per-tile convergence flags.  The carry stays in HBM and each
-  predecessor row slab is gathered by DMA (the ``idx`` table is
-  scalar-prefetched into SMEM), so VMEM use does not grow with N.  Same
-  fixed point as the Gauss-Seidel sweep.
+  ``"ell-bf"`` registry backend runs.  Off-TPU each round is the jnp
+  tile sweep; on TPU (or with explicit ``interpret=True``) it is a
+  Jacobi round as a Pallas grid over (target tile, source slab) blocks.
+  The carry stays in HBM and each predecessor row slab is gathered by
+  DMA, with the tile's slice of the ``idx``/``wgt`` tables blocked into
+  SMEM, so neither VMEM nor SMEM use grows with N.  Same fixed point as
+  the Gauss-Seidel sweep.
 * ``ell_bf_apsp_streamed`` — the frontier path: host-streamed source
   blocks.  Each block's ``(N, S)`` transposed carry converges
   independently (its own early exit) and lands in one preallocated host
@@ -60,8 +59,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.minplus import resolve_interpret
 
-__all__ = ["ell_bf_apsp", "ell_bf_apsp_streamed", "ell_relax_round_pallas",
-           "DEFAULT_TILE", "DEFAULT_BLOCK"]
+__all__ = ["ell_bf_apsp", "ell_bf_apsp_streamed", "DEFAULT_TILE",
+           "DEFAULT_BLOCK"]
 
 _INF = 1.0e18      # == repro.core.apsp._INF (no circular import; test-pinned)
 DEFAULT_TILE = 1024    # target rows per relaxation tile (CPU sweet spot)
@@ -120,13 +119,12 @@ def _relax_round_kernel(idx_ref, wgt_ref, m_hbm, mt_ref, o_ref, buf, sem, *,
                         tile: int, d_max: int):
     """One (target tile, source slab) block of a Jacobi round.
 
-    ``idx_ref``/``wgt_ref`` are the flattened ELL tables in SMEM (scalar
-    prefetch); ``m_hbm`` is the whole pre-round carry, shaped (N, S/128,
-    128) and left in HBM; ``mt_ref`` is this tile's own rows.  Every
-    predecessor row slab ``m[idx[t, j], slab]`` — one (8, 128) tile — is
-    DMA'd into ``buf[j, r]``, so VMEM holds ``d_max * tile`` slabs
-    whatever N is."""
-    base = pl.program_id(0) * (tile * d_max)
+    ``idx_ref``/``wgt_ref`` are this tile's rows of the flattened ELL
+    tables, in SMEM; ``m_hbm`` is the whole pre-round carry, shaped (N,
+    S/128, 128) and left in HBM; ``mt_ref`` is this tile's own rows.
+    Every predecessor row slab ``m[idx[t, j], slab]`` — one (8, 128)
+    tile — is DMA'd into ``buf[j, r]``, so VMEM holds ``d_max * tile``
+    slabs whatever N is."""
     slab = pl.multiple_of(pl.program_id(1) * (_SLAB // 128), _SLAB // 128)
 
     def copy(r, j, k):
@@ -135,7 +133,7 @@ def _relax_round_kernel(idx_ref, wgt_ref, m_hbm, mt_ref, o_ref, buf, sem, *,
 
     def start(r, c):
         for j in range(d_max):
-            copy(r, j, idx_ref[base + r * d_max + j]).start()
+            copy(r, j, idx_ref[0, r * d_max + j]).start()
         return c
 
     def wait(r, c):
@@ -148,7 +146,7 @@ def _relax_round_kernel(idx_ref, wgt_ref, m_hbm, mt_ref, o_ref, buf, sem, *,
     def relax(r, c):
         acc = mt_ref[r]
         for j in range(d_max):
-            acc = jnp.minimum(acc, buf[j, r] + wgt_ref[base + r * d_max + j])
+            acc = jnp.minimum(acc, buf[j, r] + wgt_ref[0, r * d_max + j])
         o_ref[r] = acc
         return c
 
@@ -157,59 +155,50 @@ def _relax_round_kernel(idx_ref, wgt_ref, m_hbm, mt_ref, o_ref, buf, sem, *,
     jax.lax.fori_loop(0, tile, relax, 0)
 
 
-def _relax_round(m, idx, wgt, *, tile: int, interpret: bool | None):
+def _relax_round_call(m, idx, wgt, *, tile: int, interpret: bool | None):
     """One Jacobi round on an aligned carry: N a multiple of ``tile``,
-    S a multiple of ``_SLAB``."""
+    S a multiple of ``_SLAB``.  Every block reads the pre-round carry
+    (the grid is unordered, so tiles never see each other's updates
+    within a round)."""
     n, d_max = idx.shape
     s = m.shape[1]
-    m3 = m.astype(jnp.float32).reshape(n, s // 128, 128)
-    block = pl.BlockSpec((tile, _SLAB // 128, 128),
-                         lambda i, j, *_: (i, j, 0))
+    m3 = m.reshape(n, s // 128, 128)
+    # (tiles, 1, tile * d_max): a block whose last two dims are whole
+    # keeps Mosaic's alignment rule satisfied when vmap prepends lanes
+    table = pl.BlockSpec((None, 1, tile * d_max), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM)
+    block = pl.BlockSpec((tile, _SLAB // 128, 128), lambda i, j: (i, j, 0))
     out = pl.pallas_call(
         functools.partial(_relax_round_kernel, tile=tile, d_max=d_max),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n // tile, s // _SLAB),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY), block],
-            out_specs=block,
-            scratch_shapes=[
-                pltpu.VMEM((d_max, tile, _SLAB // 128, 128), jnp.float32),
-                pltpu.SemaphoreType.DMA(())]),
+        grid=(n // tile, s // _SLAB),
+        in_specs=[table, table, pl.BlockSpec(memory_space=pl.ANY), block],
+        out_specs=block,
+        scratch_shapes=[
+            pltpu.VMEM((d_max, tile, _SLAB // 128, 128), jnp.float32),
+            pltpu.SemaphoreType.DMA(())],
         out_shape=jax.ShapeDtypeStruct(m3.shape, jnp.float32),
         interpret=resolve_interpret(interpret))(
-            idx.astype(jnp.int32).reshape(-1),
-            wgt.astype(jnp.float32).reshape(-1), m3, m3)
+            idx.reshape(n // tile, 1, -1), wgt.reshape(n // tile, 1, -1),
+            m3, m3)
     return out.reshape(n, s)
 
 
-def ell_relax_round_pallas(m: jax.Array, idx: jax.Array, wgt: jax.Array, *,
-                           tile: int = 64,
-                           interpret: bool | None = None):
-    """One Jacobi relaxation round as a Pallas grid over (target tile,
-    source slab) blocks.
+def _relax_round(m, idx, wgt, *, tile: int, interpret: bool | None):
+    """``_relax_round_call``, batched lane by lane under vmap: Pallas
+    batches by prepending a grid axis, and a block of the HBM carry
+    (memory space ANY) cannot take one."""
 
-    Every block reads the full pre-round carry (the grid is unordered, so
-    tiles cannot see each other's updates within a round — unlike the
-    sequential jnp sweep; both converge to the same closure).  Returns
-    ``(new_m, changed[nt])`` where ``changed[i]`` is tile ``i``'s
-    convergence flag — a tile that reports False has reached its fixed
-    point.  ``tile`` must divide N here (the jnp flavor clamps instead);
-    the source axis is padded to whole slabs internally.  The carry stays
-    in HBM and predecessor rows are gathered by DMA, so VMEM use is
-    ``d_max * tile`` slabs of ``_SLAB`` sources at any N.
-    """
-    n, d_max = _check_tables(idx, wgt)
-    if m.shape[0] != n:
-        raise ValueError(f"carry has {m.shape[0]} rows, tables have {n}")
-    if n % tile:
-        raise ValueError(f"ell_relax_round_pallas: n={n} must be a multiple "
-                         f"of tile={tile}")
-    s = m.shape[1]
-    m = m.astype(jnp.float32)
-    mp = jnp.pad(m, ((0, 0), (0, (-s) % _SLAB)), constant_values=_INF)
-    out = _relax_round(mp, idx, wgt, tile=tile, interpret=interpret)[:, :s]
-    changed = (out != m).reshape(n // tile, tile * s).any(axis=1)
-    return out, changed
+    @jax.custom_batching.custom_vmap
+    def round_(m, idx, wgt):
+        return _relax_round_call(m, idx, wgt, tile=tile, interpret=interpret)
+
+    @round_.def_vmap
+    def _lanes(axis_size, in_batched, m, idx, wgt):
+        lanes = [x if b else jnp.broadcast_to(x, (axis_size, *x.shape))
+                 for x, b in zip((m, idx, wgt), in_batched)]
+        return jax.lax.map(lambda a: round_(*a), lanes), True
+
+    return round_(m, idx, wgt)
 
 
 def _bf_fixpoint(idx, wgt, m0, *, tile: int, max_rounds: int,

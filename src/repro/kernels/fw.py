@@ -80,20 +80,20 @@ def _pivot_kernel(d_ref, o_ref):
     o_ref[...] = _tile_closure_masked(d_ref[...])
 
 
-def _row_panel_kernel(p_ref, r_ref, o_ref, *, chunk: int):
-    o_ref[...] = minplus_acc(r_ref[...], p_ref[...], r_ref[...], chunk)
+def _row_panel_kernel(p_ref, r_ref, o_ref):
+    o_ref[...] = minplus_acc(r_ref[...], p_ref[...], r_ref[...])
 
 
-def _col_panel_kernel(c_ref, p_ref, o_ref, *, chunk: int):
-    o_ref[...] = minplus_acc(c_ref[...], c_ref[...], p_ref[...], chunk)
+def _col_panel_kernel(c_ref, p_ref, o_ref):
+    o_ref[...] = minplus_acc(c_ref[...], c_ref[...], p_ref[...])
 
 
-def _outer_kernel(d_ref, c_ref, r_ref, o_ref, *, chunk: int):
-    o_ref[...] = minplus_acc(d_ref[...], c_ref[...], r_ref[...], chunk)
+def _outer_kernel(d_ref, c_ref, r_ref, o_ref):
+    o_ref[...] = minplus_acc(d_ref[...], c_ref[...], r_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("t", "chunk", "interpret"))
-def fw_apsp_pallas(w: jax.Array, *, t: int = 128, chunk: int = 8,
+@functools.partial(jax.jit, static_argnames=("t", "interpret"))
+def fw_apsp_pallas(w: jax.Array, *, t: int = 128,
                    interpret: bool | None = None) -> jax.Array:
     """Blocked Floyd-Warshall closure of an (N, N) float32 weight matrix via
     Pallas tiles.  N must be a multiple of the tile size ``t`` (callers pad
@@ -106,15 +106,12 @@ def fw_apsp_pallas(w: jax.Array, *, t: int = 128, chunk: int = 8,
     if n % t:
         raise ValueError(f"fw_apsp_pallas: n={n} must be a multiple of the "
                          f"tile size t={t} (callers pad)")
-    if t % chunk:
-        raise ValueError(f"fw_apsp_pallas: t={t} must be a multiple of "
-                         f"chunk={chunk}")
     interpret = resolve_interpret(interpret)
     nb = n // t
     d = w.astype(jnp.float32)
 
     row_call = pl.pallas_call(
-        functools.partial(_row_panel_kernel, chunk=chunk),
+        _row_panel_kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((t, t), lambda j: (0, 0)),
                   pl.BlockSpec((t, t), lambda j: (0, j))],
@@ -122,7 +119,7 @@ def fw_apsp_pallas(w: jax.Array, *, t: int = 128, chunk: int = 8,
         out_shape=jax.ShapeDtypeStruct((t, n), jnp.float32),
         interpret=interpret)
     col_call = pl.pallas_call(
-        functools.partial(_col_panel_kernel, chunk=chunk),
+        _col_panel_kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((t, t), lambda i: (i, 0)),
                   pl.BlockSpec((t, t), lambda i: (0, 0))],
@@ -130,7 +127,7 @@ def fw_apsp_pallas(w: jax.Array, *, t: int = 128, chunk: int = 8,
         out_shape=jax.ShapeDtypeStruct((n, t), jnp.float32),
         interpret=interpret)
     outer_call = pl.pallas_call(
-        functools.partial(_outer_kernel, chunk=chunk),
+        _outer_kernel,
         grid=(nb, nb),
         in_specs=[pl.BlockSpec((t, t), lambda i, j: (i, j)),
                   pl.BlockSpec((t, t), lambda i, j: (i, 0)),

@@ -38,35 +38,28 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return interpret
 
 
-def minplus_acc(acc: jax.Array, a: jax.Array, b: jax.Array,
-                chunk: int) -> jax.Array:
+def minplus_acc(acc: jax.Array, a: jax.Array, b: jax.Array) -> jax.Array:
     """``min(acc, A (min,+) B)`` for in-kernel values ``a`` (m, K) and
-    ``b`` (K, n).  K is walked in ``chunk``-wide slabs, each unrolled with
-    static offsets (shared by the blocked Floyd-Warshall kernels)."""
-    k = a.shape[1]
-    for c0 in range(0, k, chunk):
-        a_c = a[:, c0:c0 + chunk]
-        b_c = b[c0:c0 + chunk, :]
-        for kk in range(a_c.shape[1]):
-            acc = jnp.minimum(acc, a_c[:, kk:kk + 1] + b_c[kk:kk + 1, :])
+    ``b`` (K, n), K unrolled with static offsets (shared by the blocked
+    Floyd-Warshall kernels)."""
+    for kk in range(a.shape[1]):
+        acc = jnp.minimum(acc, a[:, kk:kk + 1] + b[kk:kk + 1, :])
     return acc
 
 
-def _minplus_kernel(a_ref, b_ref, o_ref, *, chunk: int):
+def _minplus_kernel(a_ref, b_ref, o_ref):
     """One (bm, bn) output tile; min-accumulate over the k grid axis."""
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, _NEG_INF_SAFE)
 
-    o_ref[...] = minplus_acc(o_ref[...], a_ref[...], b_ref[...], chunk)
+    o_ref[...] = minplus_acc(o_ref[...], a_ref[...], b_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "chunk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def minplus_matmul_pallas(a: jax.Array, b: jax.Array, *,
                           bm: int = 128, bn: int = 128, bk: int = 128,
-                          chunk: int = 8,
                           interpret: bool | None = None) -> jax.Array:
     """Tropical matmul via pallas_call.  Inputs are (M, K) and (K, N) float32;
     entries >= 1e38 are treated as +inf.  Shapes must be multiples of the
@@ -85,14 +78,10 @@ def minplus_matmul_pallas(a: jax.Array, b: jax.Array, *,
             f"minplus_matmul_pallas: shapes must be multiples of the block "
             f"sizes: a.shape={a.shape}, b.shape={b.shape} with blocks "
             f"(bm={bm}, bn={bn}, bk={bk}); callers pad (see ops.minplus_matmul)")
-    if bk % chunk:
-        raise ValueError(
-            f"minplus_matmul_pallas: bk={bk} must be a multiple of "
-            f"chunk={chunk}")
 
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
-        functools.partial(_minplus_kernel, chunk=chunk),
+        _minplus_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -2,6 +2,8 @@
 serialized executables with zero new XLA compiles, a bad entry is
 recompiled, a program that cannot be lowered raises, and results are
 identical either way."""
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -127,9 +129,14 @@ def test_entries_record_custom_calls(tmp_path):
 def test_default_dirs_stay_in_checkout_or_env(monkeypatch, tmp_path):
     monkeypatch.delenv("REPRO_AOT_CACHE_DIR", raising=False)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    checkout = aotcache._CHECKOUT
+    checkout = Path(__file__).resolve().parents[1]
     assert aotcache.cache_root() == checkout / ".jax_cache"
     assert aotcache.default_dir().is_relative_to(checkout)
+    # an installed copy has no checkout to default to: it says so
+    monkeypatch.setattr(aotcache, "__file__",
+                        str(tmp_path / "site-packages/repro/core/aotcache.py"))
+    with pytest.raises(RuntimeError, match="JAX_COMPILATION_CACHE_DIR"):
+        aotcache.cache_root()
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert aotcache.cache_root() == tmp_path
     assert aotcache.default_dir().is_relative_to(tmp_path)

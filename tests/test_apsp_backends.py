@@ -8,6 +8,8 @@ tie-splitting included.  Weights quantized to multiples of 1/8 make
 float32 path sums exact, so those checks can demand bit-equality rather
 than tolerances.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -296,28 +298,52 @@ def test_ell_bf_streamed_matches_full_solve():
     assert rounds >= 1
 
 
+def _ell_weighted(n, d, seed):
+    """Degree-``d`` ELL tables with random, asymmetric, non-tied lengths
+    (the pads keep their ``_INF``)."""
+    g = random_regular_ell(n, d, seed=seed)
+    rng = np.random.default_rng(seed)
+    wgt = np.where(g.wgt < _INF / 2,
+                   rng.uniform(0.5, 2.0, g.wgt.shape), g.wgt)
+    return jnp.asarray(g.idx), jnp.asarray(wgt, jnp.float32)
+
+
 def test_ell_pallas_round_matches_jacobi_reference():
-    """One Pallas relaxation round (interpret mode) == the plain Jacobi
-    update min(m, min_j wgt[:, j] + m[idx[:, j], :]) with per-tile changed
-    flags."""
-    g = random_regular_ell(32, 4, seed=7)
-    idx, wgt = jnp.asarray(g.idx), jnp.asarray(g.wgt)
-    m = kell._full_init(idx, wgt)
-    ref = np.asarray(m)
+    """One round of the Pallas flavor (interpret mode) == the plain
+    Jacobi update min(m, min_j wgt[:, j] + m[idx[:, j], :]).  N=40 is no
+    multiple of the kernel's target tile, so the padding and crop run."""
+    idx, wgt = _ell_weighted(40, 4, seed=7)
+    assert 40 % kell._pallas_tile(4)
+    m = np.asarray(kell._full_init(idx, wgt))
     # cand[t, j, s] = wgt[t, j] + m[idx[t, j], s]
-    cand = np.asarray(wgt)[:, :, None] + np.asarray(m)[np.asarray(g.idx)]
-    ref2 = np.minimum(ref, cand.min(axis=1))
-    out, changed = kell.ell_relax_round_pallas(m, idx, wgt, tile=8,
-                                               interpret=True)
-    assert np.array_equal(np.asarray(out), ref2)
-    tiles = np.asarray(changed)
-    per_tile = (ref2 != ref).any(axis=1).reshape(-1, 8).any(axis=1)
-    assert np.array_equal(tiles, per_tile)
-    # converged input reports no change anywhere
-    d, _ = kell.ell_bf_apsp(idx, wgt)
-    _, quiet = kell.ell_relax_round_pallas(jnp.asarray(np.asarray(d).T),
-                                           idx, wgt, tile=8, interpret=True)
-    assert not np.asarray(quiet).any()
+    cand = np.asarray(wgt)[:, :, None] + m[np.asarray(idx)]
+    ref = np.minimum(m, cand.min(axis=1))
+    d, rounds = kell.ell_bf_apsp(idx, wgt, max_rounds=1, use_pallas=True,
+                                 interpret=True)
+    assert int(rounds) == 1
+    assert np.array_equal(np.asarray(d).T, ref)
+
+
+@pytest.mark.parametrize("max_rounds", [1, 2, None])
+def test_ell_bf_pallas_flavor_matches_jnp(max_rounds):
+    """The Pallas flavor the chip runs (interpret mode, N=40 padded to a
+    whole tile, sources to a whole slab) gives the jnp flavor's
+    distances bit for bit, round count included, alone and under vmap.
+    With one tile the jnp sweep is Jacobi too, so capped runs agree."""
+    cases = [_ell_weighted(40, 4, seed=s) for s in (3, 4)]
+    kw = dict(max_rounds=max_rounds, interpret=True)
+    ref = [kell.ell_bf_apsp(i, w, **kw) for i, w in cases]
+    for (i, w), (d_ref, r_ref) in zip(cases, ref):
+        d, r = kell.ell_bf_apsp(i, w, use_pallas=True, **kw)
+        assert np.array_equal(np.asarray(d), np.asarray(d_ref))
+        assert int(r) == int(r_ref)
+    idx = jnp.stack([i for i, _ in cases])
+    wgt = jnp.stack([w for _, w in cases])
+    d, r = jax.vmap(functools.partial(kell.ell_bf_apsp_impl, use_pallas=True,
+                                      **kw))(idx, wgt)
+    for k, (d_ref, r_ref) in enumerate(ref):
+        assert np.array_equal(np.asarray(d[k]), np.asarray(d_ref))
+        assert int(r[k]) == int(r_ref)
 
 
 def test_ell_bf_requires_static_d_max():
@@ -373,14 +399,14 @@ def test_ell_bf_vmaps_like_dense_backends():
 
 def test_fw_pallas_tiles_match_jnp():
     w = _w_random(32, 3)
-    tiled = kfw.fw_apsp_pallas(w, t=8, chunk=8, interpret=True)   # 4x4 tiles
+    tiled = kfw.fw_apsp_pallas(w, t=8, interpret=True)   # 4x4 tiles
     plain = kfw.fw_apsp_jnp(w)
     assert np.array_equal(np.asarray(tiled), np.asarray(plain))
 
 
 def test_fw_pallas_single_tile_fast_path():
     w = _w_random(16, 4)
-    one = kfw.fw_apsp_pallas(w, t=16, chunk=8, interpret=True)
+    one = kfw.fw_apsp_pallas(w, t=16, interpret=True)
     assert np.array_equal(np.asarray(one), np.asarray(kfw.fw_apsp_jnp(w)))
 
 
@@ -389,9 +415,6 @@ def test_fw_pallas_validates_shapes():
         kfw.fw_apsp_pallas(jnp.zeros((8, 12)), t=4, interpret=True)
     with pytest.raises(ValueError, match="multiple of the"):
         kfw.fw_apsp_pallas(jnp.zeros((10, 10)), t=4, interpret=True)
-    with pytest.raises(ValueError, match="chunk"):
-        kfw.fw_apsp_pallas(jnp.zeros((16, 16)), t=8, chunk=3,
-                           interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +494,3 @@ def test_minplus_matmul_pallas_raises_on_bad_inputs():
         minplus.minplus_matmul_pallas(jnp.zeros((100, 128)),
                                       jnp.zeros((128, 128)),
                                       interpret=True)
-    with pytest.raises(ValueError, match="chunk"):
-        minplus.minplus_matmul_pallas(jnp.zeros((128, 128)),
-                                      jnp.zeros((128, 128)),
-                                      chunk=7, interpret=True)

@@ -1,4 +1,5 @@
-"""The APSP Pallas kernels compile for a TPU v5e at N=1024.
+"""The APSP Pallas kernels compile for a TPU v5e at N=1024, and the ELL
+round at the N=16384 frontier it exists for.
 
 No chip is needed: the TPU compiler compiles for a described, unattached
 v5e, and refuses what the chip's compiler would refuse (lowering gaps,
@@ -23,6 +24,7 @@ from repro.kernels import fw as kfw
 from repro.kernels import minplus
 
 N = 1024
+N_FRONTIER = 16384   # scale_bench's ell-bf frontier
 D_MAX = 16
 
 
@@ -65,19 +67,28 @@ def test_blocked_fw_compiles_for_v5e(one_chip):
         w, t=128, interpret=False).compile())
 
 
+def _compile_ell(sharding, n):
+    """The closure the ``ell-bf`` backend runs on the chip, whose while
+    loop holds the Pallas round."""
+    idx = _spec(sharding, (n, D_MAX), jnp.int32)
+    wgt = _spec(sharding, (n, D_MAX))
+    return kell.ell_bf_apsp.lower(idx, wgt, max_rounds=8, use_pallas=True,
+                                  interpret=False).compile()
+
+
 def test_ell_round_compiles_for_v5e(one_chip):
-    m = _spec(one_chip, (N, N))
-    idx = _spec(one_chip, (N, D_MAX), jnp.int32)
-    wgt = _spec(one_chip, (N, D_MAX))
-    fn = jax.jit(functools.partial(kell.ell_relax_round_pallas,
-                                   tile=kell._pallas_tile(D_MAX),
-                                   interpret=False))
-    _assert_kernel(fn.lower(m, idx, wgt).compile())
+    _assert_kernel(_compile_ell(one_chip, N))
+
+
+def test_ell_round_fits_smem_at_frontier(one_chip):
+    """Each grid step blocks only its target tile's rows of the tables
+    into SMEM (1 MiB on v5e); whole tables prefetched there would need
+    2 MiB at this N."""
+    _assert_kernel(_compile_ell(one_chip, N_FRONTIER))
 
 
 def test_ell_closure_compiles_vmapped_for_v5e(one_chip):
-    """The solvers run the closure per lane under vmap: the scalar-
-    prefetched tables batch through Pallas's explicit lane loop."""
+    """The solvers run the closure per lane under vmap."""
     idx = _spec(one_chip, (4, N, D_MAX), jnp.int32)
     wgt = _spec(one_chip, (4, N, D_MAX))
     fn = jax.jit(jax.vmap(functools.partial(
