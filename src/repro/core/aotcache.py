@@ -17,9 +17,13 @@ skips XLA entirely:
   as ``errors``.  A program that fails to lower or compile raises — the
   cache never swaps a kernel the compiler refused for some other path.
   Each entry
-  records the compiled program's custom-call targets; ``last`` holds
-  those of the most recent call (``"tpu_custom_call"`` = a Mosaic
-  kernel ran).
+  records the compiled program's custom-call targets and its device op
+  scopes (``spans.scopes_of_hlo``); ``last`` holds those of the most
+  recent call (``"tpu_custom_call"`` = a Mosaic kernel ran).
+* ``dispatch(fn, tag, args, static_kw, aot=, sharding=)`` — how every
+  batch solver runs its program: through ``aot`` when given and the call
+  is unsharded, else as a plain jit call; either way the program is
+  noted for ``spans.op_scopes()``.
 * ``resolve(knob)`` — map an engine-level knob (None / bool / directory
   path / ``AotCache``) to an ``AotCache`` or ``None``.  ``None`` defers
   to the ``REPRO_AOT_CACHE`` env var (truthy enables;
@@ -52,7 +56,9 @@ from typing import Any, Mapping, Sequence
 
 import jax
 
-__all__ = ["AotCache", "resolve", "default_dir", "cache_root",
+from repro.core import spans
+
+__all__ = ["AotCache", "dispatch", "resolve", "default_dir", "cache_root",
            "enable_jax_cache", "stats", "reset_stats"]
 
 _COUNTERS = {"compiles": 0, "hits": 0, "misses": 0, "errors": 0}
@@ -148,6 +154,36 @@ def _abstract(x: Any) -> tuple:
     return (tuple(a.shape), str(a.dtype))
 
 
+def _signature(tag: Sequence[str], args: Sequence[Any],
+               static_kw: Mapping[str, Any]) -> tuple:
+    """What one program is compiled for: tag, arg shapes/dtypes, static
+    kwargs."""
+    return (tuple(tag), tuple(_abstract(a) for a in args),
+            tuple(sorted((k, repr(v)) for k, v in static_kw.items())))
+
+
+def dispatch(fn: Any, tag: Sequence[str], args: Sequence[Any],
+             static_kw: Mapping[str, Any], *, aot: "AotCache | None" = None,
+             sharding: Any = None) -> Any:
+    """Run a batch solver's program ``fn(*args, **static_kw)``: through
+    ``aot`` when given and ``sharding`` is None (sharded executables are
+    not cached), else as the jit call.  The program is noted for
+    ``spans.op_scopes()``: by the cache entry's scope map, or by its
+    ``Lowered``, whose compile jit's caches serve with the executable
+    this call runs."""
+    with warnings.catch_warnings():
+        # outputs are per-lane scalars, so XLA reports a donation unused —
+        # expected, not actionable
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        if aot is not None and sharding is None:
+            return aot.call(fn, tag, args, static_kw)
+        key = ("jit",) + _signature(tag, args, static_kw) + (repr(sharding),)
+        if not spans.noted(key):
+            spans.note_program(key, fn.lower(*args, **static_kw).compile)
+        return fn(*args, **static_kw)
+
+
 class AotCache:
     """Directory-backed store of serialized compiled executables."""
 
@@ -166,10 +202,7 @@ class AotCache:
             devs[0].device_kind if devs else "none",
             len(devs),
             source_digest(),
-            tuple(tag),
-            tuple(_abstract(a) for a in args),
-            tuple(sorted((k, repr(v)) for k, v in static_kw.items())),
-        ))
+        ) + _signature(tag, args, static_kw))
         return hashlib.sha256(fp.encode()).hexdigest()[:32]
 
     def _path(self, key: str) -> Path:
@@ -192,6 +225,7 @@ class AotCache:
                 blob = pickle.loads(path.read_bytes())
                 compiled = se.deserialize_and_load(
                     blob["payload"], blob["in_tree"], blob["out_tree"])
+                scopes = blob["meta"]["scopes"]
                 out = compiled(*args)
             except Exception as e:
                 _COUNTERS["errors"] += 1
@@ -202,13 +236,17 @@ class AotCache:
             else:
                 _COUNTERS["hits"] += 1
                 self.last = blob["meta"]
+                spans.note_program(path.stem, scopes)
                 return out
 
         _COUNTERS["misses"] += 1
         compiled = jitfn.lower(*args, **static_kw).compile()
         _COUNTERS["compiles"] += 1
+        text = compiled.as_text()
         self.last = {"tag": tuple(tag), "custom_calls": sorted(set(
-            re.findall(r'custom_call_target="([^"]+)"', compiled.as_text())))}
+            re.findall(r'custom_call_target="([^"]+)"', text))),
+            "scopes": spans.scopes_of_hlo(text)}
+        spans.note_program(path.stem, self.last["scopes"])
         try:
             payload, in_tree, out_tree = se.serialize(compiled)
             tmp = path.with_suffix(f".tmp{os.getpid()}")

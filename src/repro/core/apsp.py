@@ -66,6 +66,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import scoped
 from repro.kernels import ell as kell
 from repro.kernels import fw as kfw
 from repro.kernels import ops as kops
@@ -146,6 +147,7 @@ def _pack_ell(w: jax.Array, d_max: int) -> tuple[jax.Array, jax.Array]:
     return idx, wgt
 
 
+@scoped("apsp_fwd")
 def _apsp_forward(w: jax.Array, backend: str, interpret: bool | None,
                   d_max: int | None = None, max_rounds: int | None = None):
     n = w.shape[0]
@@ -392,6 +394,7 @@ def _apsp_fwd(w, backend, interpret, d_max, max_rounds):
     return d, (w, d)
 
 
+@scoped("apsp_bwd")
 def _apsp_bwd(backend, interpret, d_max, max_rounds, res, g):
     w, d = res
     if resolve_backend(backend, w.shape[0]) == "ell-bf":

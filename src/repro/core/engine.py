@@ -52,7 +52,7 @@ from typing import Any, Callable, Mapping, Protocol, Sequence, runtime_checkable
 import numpy as np
 
 from repro.core import adversarial as adversarial_mod
-from repro.core import aotcache, lp, mcf, primal, routing
+from repro.core import aotcache, lp, mcf, primal, routing, spans
 from repro.core import apsp as apsp_mod
 from repro.core import traffic as traffic_mod
 from repro.core.graphs import Topology, as_cap
@@ -327,10 +327,12 @@ class _PlannedEngine:
                 self._disconnected_result(), frac)
         return topo, dems[0], frac, None
 
+    @spans.span("engine.solve_batch")
     def solve_batch(self, topos, dems) -> list[ThroughputResult]:
         _check_batch_lengths(topos, dems)
-        topos, dems = self._coarsen_instances(topos, dems)
-        dems, dropped = self._apply_disconnection_policy(topos, dems)
+        with spans.span("engine.prepare"):
+            topos, dems = self._coarsen_instances(topos, dems)
+            dems, dropped = self._apply_disconnection_policy(topos, dems)
         live = [i for i, f in enumerate(dropped) if f is None or f < 1.0]
         plan = self.plan([topos[i] for i in live], [dems[i] for i in live])
         self.last_plan = plan.stats
@@ -705,20 +707,22 @@ def run_sweeps(items: Sequence[tuple[Sweep, Callable[[float, int], Topology]]],
     without the hook.
     """
     eng = as_engine(engine)
-    topos, dems, spans = [], [], []
-    for sweep, build_fn in items:
-        start = len(topos)
-        for x in sweep.xs:
-            for seed in sweep.seeds():
-                topo = build_fn(x, seed)
-                dem = traffic_mod.make(sweep.traffic, topo.servers, seed + 1,
-                                       **sweep.traffic_kw)
-                topos.append(topo)
-                dems.append(dem)
-        spans.append(start)
-    results = eng.solve_batch(topos, dems) if topos else []
+    with spans.span("engine.run_sweeps"):
+        topos, dems, starts = [], [], []
+        with spans.span("sweep.build"):
+            for sweep, build_fn in items:
+                start = len(topos)
+                for x in sweep.xs:
+                    for seed in sweep.seeds():
+                        topo = build_fn(x, seed)
+                        dem = traffic_mod.make(sweep.traffic, topo.servers,
+                                               seed + 1, **sweep.traffic_kw)
+                        topos.append(topo)
+                        dems.append(dem)
+                starts.append(start)
+        results = eng.solve_batch(topos, dems) if topos else []
     out: list[list[SweepPoint]] = []
-    for (sweep, _), start in zip(items, spans):
+    for (sweep, _), start in zip(items, starts):
         points = []
         for pi, x in enumerate(sweep.xs):
             lo = start + pi * sweep.runs

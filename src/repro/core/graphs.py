@@ -19,6 +19,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core import spans
+
 __all__ = [
     "Topology",
     "EllGraph",
@@ -373,15 +375,17 @@ def _pair_stubs(stubs_a: np.ndarray, stubs_b: np.ndarray | None,
     return np.stack([a[:k], b[:k]], axis=1)
 
 
+@spans.span("graphs.repair", iterations=0, stalled=0)
 def _repair_multigraph(adj: np.ndarray, rng: np.random.Generator,
                        max_iter: int = 4_000) -> np.ndarray:
     """Remove self-loops and multi-edges by double-edge swaps, preserving the
     degree sequence.  ``adj`` is an integer multi-adjacency matrix."""
     adj = adj.copy()
-    for _ in range(max_iter):
+    for it in range(max_iter):
         bad_self = np.flatnonzero(np.diag(adj) > 0)
         multi = np.argwhere(np.triu(adj, 1) > 1)
         if len(bad_self) == 0 and len(multi) == 0:
+            spans.current().set(iterations=it)
             return adj
         # pick one offending placement
         if len(bad_self) > 0:
@@ -630,6 +634,7 @@ def _biased_two_cluster_cap(
     return adj.astype(np.float64) * capacity, labels
 
 
+@spans.span("graphs.repair", iterations=0, stalled=0)
 def _repair_two_cluster(adj: np.ndarray, na: int, rng: np.random.Generator,
                         max_iter: int = 20_000) -> np.ndarray:
     """Like _repair_multigraph but swaps only with a partner edge of the same
@@ -653,10 +658,11 @@ def _repair_two_cluster(adj: np.ndarray, na: int, rng: np.random.Generator,
     # hopeless repair here used to cost seconds per candidate
     best_bad = np.inf
     stall = 0
-    for _ in range(max_iter):
+    for it in range(max_iter):
         bad_self = np.flatnonzero(np.diag(adj) > 0)
         multi = np.argwhere(np.triu(adj, 1) > 1)
         if len(bad_self) == 0 and len(multi) == 0:
+            spans.current().set(iterations=it)
             return adj
         bad = len(bad_self) + len(multi)
         if bad < best_bad:
@@ -664,6 +670,7 @@ def _repair_two_cluster(adj: np.ndarray, na: int, rng: np.random.Generator,
         else:
             stall += 1
             if stall > 200:
+                spans.current().set(iterations=it, stalled=1)
                 break
         if len(bad_self) > 0:
             i = int(rng.integers(len(bad_self)))
@@ -713,6 +720,8 @@ def _repair_two_cluster(adj: np.ndarray, na: int, rng: np.random.Generator,
                 adj[p, q] += 1
                 adj[q, p] += 1
             break
+    else:
+        spans.current().set(iterations=max_iter)
     # iteration budget exhausted: a cluster may be too dense for a simple
     # graph (e.g. strongly-biased intra wiring).  Keep the remaining
     # multi-edges as parallel links (capacities sum — physically valid) and

@@ -46,16 +46,17 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import aotcache
 from repro.core import apsp as apsp_mod
 from repro.core.apsp import _INF, normalize_backend
 from repro.core.graphs import (Topology, as_cap, connected_components,
                                degree_stats)
+from repro.core.spans import scoped
 from repro.kernels import ops as kops
 
 __all__ = ["DualResult", "DualBatchResult", "DualDemgradBatchResult",
@@ -309,6 +310,7 @@ def _descend(cap: jax.Array, dem: jax.Array, n_valid: jax.Array,
         i, _, _, _, _, _, done = state
         return (i < iters) & ~done
 
+    @scoped("descent_update")
     def step(state):
         i, z, m, v, best, ref_best, _ = state
         (_, ratio), g = grad_fn(z)
@@ -539,18 +541,9 @@ def solve_dual_batch(caps, dems, *, n_valid=None, iters: int = 800,
     static_kw = dict(iters=iters, check_every=check_every,
                      backend=backend, interpret=interpret,
                      d_max=d_max, max_rounds=max_rounds)
-    with warnings.catch_warnings():
-        # donated buffers alias outputs only when shapes permit; here the
-        # outputs are per-lane scalars, so XLA reports the donation unused —
-        # expected, not actionable
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        if aot is not None and sharding is None:
-            best, final, it = aot.call(
-                fn, ("dual", "donated" if donate else "plain"),
-                args, static_kw)
-        else:
-            best, final, it = fn(*args, **static_kw)
+    best, final, it = aotcache.dispatch(
+        fn, ("dual", "donated" if donate else "plain"), args, static_kw,
+        aot=aot, sharding=sharding)
     if not block:
         return DualBatchResult(best, final, it)
     return DualBatchResult(np.asarray(best), np.asarray(final),
@@ -604,15 +597,9 @@ def solve_dual_demgrad_batch(caps, dems, *, n_valid=None, iters: int = 800,
     static_kw = dict(iters=iters, check_every=check_every,
                      backend=backend, interpret=interpret,
                      d_max=d_max, max_rounds=max_rounds)
-    with warnings.catch_warnings():
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        if aot is not None and sharding is None:
-            best, final, it, g = aot.call(
-                fn, ("dual-demgrad", "donated" if donate else "plain"),
-                args, static_kw)
-        else:
-            best, final, it, g = fn(*args, **static_kw)
+    best, final, it, g = aotcache.dispatch(
+        fn, ("dual-demgrad", "donated" if donate else "plain"), args,
+        static_kw, aot=aot, sharding=sharding)
     if not block:
         return DualDemgradBatchResult(best, final, it, g)
     return DualDemgradBatchResult(np.asarray(best), np.asarray(final),

@@ -51,7 +51,7 @@ from typing import Any, Mapping, Sequence
 
 import numpy as np
 
-from repro.core import aotcache, mcf, primal, routing
+from repro.core import aotcache, mcf, primal, routing, spans
 from repro.core.graphs import Topology, as_cap, degree_stats
 
 __all__ = ["bucket_size", "device_count", "compile_cache_sizes", "Chunk",
@@ -224,6 +224,7 @@ class BatchPlan:
                                        for c in chunks})))
 
     @classmethod
+    @spans.span("plan.build")
     def build(cls, topos: Sequence[Topology | np.ndarray],
               dems: Sequence[np.ndarray], *,
               bucket: str | int | None = "pow2",
@@ -364,13 +365,21 @@ class BatchPlan:
                       and "mean_degree" not in solver_kw)
         pending = []
         for chunk in self.chunks:
-            capp, demp, n_valid = self._pack(chunk)
-            kw = ({**solver_kw, **self._density_hints(chunk)}
-                  if want_hints else solver_kw)
-            pending.append(dispatch(capp, demp, n_valid, sharding, kw))
+            with spans.span("plan.pack"):
+                capp, demp, n_valid = self._pack(chunk)
+                kw = ({**solver_kw, **self._density_hints(chunk)}
+                      if want_hints else solver_kw)
+            with spans.span("plan.dispatch"):
+                pending.append(dispatch(capp, demp, n_valid, sharding, kw))
         # ONE host sync for the whole plan: chunks overlap on-device while
         # the host is still packing/dispatching later ones
-        jax.block_until_ready([list(r.values()) for r in pending])
+        with spans.span("plan.sync"):
+            jax.block_until_ready([list(r.values()) for r in pending])
+        with spans.span("plan.unpack"):
+            return self._unpack(pending)
+
+    def _unpack(self, pending: list[dict]) -> list[InstanceSolve]:
+        """Scatter the synced per-chunk results back into input order."""
         stats = self.stats.as_dict()   # values immutable; copied per result
         out: list[InstanceSolve | None] = [None] * len(self.caps)
         for ci, (chunk, res) in enumerate(zip(self.chunks, pending)):

@@ -49,16 +49,17 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import aotcache
 from repro.core.apsp import normalize_backend
 from repro.core.graphs import Topology, as_cap
 from repro.core.mcf import (_INF, apsp, jit_cache_size,
                             resolve_backend_density)
+from repro.core.spans import scoped
 from repro.kernels import ops as kops
 
 __all__ = ["PrimalResult", "PrimalBatchResult", "solve_primal",
@@ -152,6 +153,7 @@ def _solve_one(cap: jax.Array, dem: jax.Array, n_valid: jax.Array,
         done = state[-1]
         return (i < iters) & ~done
 
+    @scoped("descent_update")
     def step(state):
         i, z, m, v, loads, best_lb, best_ub, ref_gap, _ = state
         l = jnp.exp(z)
@@ -325,16 +327,9 @@ def solve_primal_batch(caps, dems, *, n_valid=None, iters: int = 800,
     static_kw = dict(iters=iters, check_every=check_every,
                      backend=backend, interpret=interpret,
                      d_max=d_max, max_rounds=max_rounds)
-    with warnings.catch_warnings():
-        # outputs are per-lane scalars, so XLA reports the donation unused
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        if aot is not None and sharding is None:
-            lb, ub, util, it = aot.call(
-                fn, ("primal", "donated" if donate else "plain"),
-                args, static_kw)
-        else:
-            lb, ub, util, it = fn(*args, **static_kw)
+    lb, ub, util, it = aotcache.dispatch(
+        fn, ("primal", "donated" if donate else "plain"), args, static_kw,
+        aot=aot, sharding=sharding)
     if not block:
         return PrimalBatchResult(lb, ub, util, it)
     return PrimalBatchResult(np.asarray(lb), np.asarray(ub),

@@ -54,12 +54,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import aotcache
 from repro.core.apsp import normalize_backend
 from repro.core.graphs import Topology, as_cap
 from repro.core.mcf import (_INF, _descend, apsp, jit_cache_size,
@@ -478,16 +478,9 @@ def solve_ecmp_batch(caps, dems, *, n_valid=None, iters: int = 800,
                      interpret=interpret, d_max=d_max,
                      max_rounds=max_rounds,
                      hops=_resolve_hops(caps.shape[1], hops))
-    with warnings.catch_warnings():
-        # outputs are per-lane scalars, so XLA reports the donation unused
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        if aot is not None and sharding is None:
-            lb, ub, util, it = aot.call(
-                fn, ("ecmp", "donated" if donate else "plain"),
-                args, static_kw)
-        else:
-            lb, ub, util, it = fn(*args, **static_kw)
+    lb, ub, util, it = aotcache.dispatch(
+        fn, ("ecmp", "donated" if donate else "plain"), args, static_kw,
+        aot=aot, sharding=sharding)
     if not block:
         return RoutingBatchResult(lb, ub, util, it)
     return RoutingBatchResult(np.asarray(lb), np.asarray(ub),
@@ -530,15 +523,9 @@ def solve_ksp_batch(caps, dems, *, n_valid=None, k: int = DEFAULT_K,
                      interpret=interpret, d_max=d_max,
                      max_rounds=max_rounds,
                      hops=_resolve_hops(caps.shape[1], hops))
-    with warnings.catch_warnings():
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        if aot is not None and sharding is None:
-            lb, ub, util, it = aot.call(
-                fn, ("ksp", "donated" if donate else "plain"),
-                args, static_kw)
-        else:
-            lb, ub, util, it = fn(*args, **static_kw)
+    lb, ub, util, it = aotcache.dispatch(
+        fn, ("ksp", "donated" if donate else "plain"), args, static_kw,
+        aot=aot, sharding=sharding)
     if not block:
         return RoutingBatchResult(lb, ub, util, it)
     return RoutingBatchResult(np.asarray(lb), np.asarray(ub),
