@@ -645,11 +645,20 @@ def _repair_two_cluster(adj: np.ndarray, na: int, rng: np.random.Generator,
     * cross offender (a1,b1) + cross partner (a2,b2) with a in A, b in B:
                                                    -> (a1,b2),(a2,b1)
     Self-loops only ever occur inside a cluster (a cross pairing has distinct
-    endpoints by construction)."""
-    adj = adj.copy()
+    endpoints by construction).
 
-    def is_cross(u, v):
-        return (u < na) != (v < na)
+    Each iteration screens up to 600 shuffled partners at once and applies
+    the first valid one.  Shuffling an index array draws exactly what
+    shuffling the partner list would (``Generator.shuffle`` makes the same
+    Fisher–Yates draws for both), so the walk depends only on the seed."""
+    adj = adj.copy()
+    n = len(adj)
+    in_a = np.arange(n) < na
+    iu, ju = np.triu_indices(n, 1)
+    # where a partner of each class may sit: cross pairs above the diagonal
+    # (each edge once), intra pairs within one cluster in both orientations
+    cross_pairs = np.triu(in_a[:, None] != in_a[None, :], 1)
+    intra_pairs = {True: np.outer(in_a, in_a), False: np.outer(~in_a, ~in_a)}
 
     # stall detection: when no swap reduces the offender count for a whole
     # window (a cluster too dense to be simple), jump straight to the
@@ -659,8 +668,8 @@ def _repair_two_cluster(adj: np.ndarray, na: int, rng: np.random.Generator,
     best_bad = np.inf
     stall = 0
     for it in range(max_iter):
-        bad_self = np.flatnonzero(np.diag(adj) > 0)
-        multi = np.argwhere(np.triu(adj, 1) > 1)
+        bad_self = np.flatnonzero(adj.diagonal() > 0)
+        multi = np.flatnonzero(adj[iu, ju] > 1)
         if len(bad_self) == 0 and len(multi) == 0:
             spans.current().set(iterations=it)
             return adj
@@ -676,50 +685,50 @@ def _repair_two_cluster(adj: np.ndarray, na: int, rng: np.random.Generator,
             i = int(rng.integers(len(bad_self)))
             u = v = int(bad_self[i])
         else:
-            i = int(rng.integers(len(multi)))
-            u, v = int(multi[i][0]), int(multi[i][1])
-        cross = is_cross(u, v)
-        xs, ys = np.nonzero(np.triu(adj, 1) if cross else adj)
-        # candidate partners of the same class — for intra offenders the
-        # partner must be in the SAME cluster (an other-cluster intra swap
-        # would mint two cross edges and break the bias semantics)
-        same = [(int(x), int(y)) for x, y in zip(xs, ys)
-                if is_cross(x, y) == cross
-                and (cross or (x < na) == (u < na))]
-        rng.shuffle(same)
-        for x, y in same[:600]:
-            if cross:
-                a1, b1 = (u, v) if u < na else (v, u)
-                a2, b2 = (x, y) if x < na else (y, x)
-                if a1 == a2 or b1 == b2:
-                    continue
-                if adj[a1, b2] > 0 or adj[a2, b1] > 0:
-                    continue
-                new_edges = ((a1, b2), (a2, b1))
-                old_edges = ((a1, b1), (a2, b2))
+            i = int(multi[rng.integers(len(multi))])
+            u, v = int(iu[i]), int(ju[i])
+        cross = bool(in_a[u] != in_a[v])
+        # candidate partners of the same class, in np.nonzero's row-major
+        # order — for intra offenders the partner must be in the SAME
+        # cluster (an other-cluster intra swap would mint two cross edges
+        # and break the bias semantics)
+        xs, ys = np.nonzero((adj > 0) & (
+            cross_pairs if cross else intra_pairs[bool(in_a[u])]))
+        order = np.arange(len(xs))
+        rng.shuffle(order)
+        xs, ys = xs[order[:600]], ys[order[:600]]
+        if cross:
+            a1, b1 = (u, v) if u < na else (v, u)
+            a2 = np.where(in_a[xs], xs, ys)
+            b2 = np.where(in_a[xs], ys, xs)
+            ok = ((a2 != a1) & (b2 != b1)
+                  & (adj[a1, b2] == 0) & (adj[a2, b1] == 0))
+        else:
+            # four distinct endpoints (three for a self-loop, whose swap
+            # (u,u) + (x,y) -> (u,x),(u,y)), and no new edge already there
+            ok = ((xs != u) & (xs != v) & (ys != u) & (ys != v) & (xs != ys)
+                  & (adj[u, xs] == 0) & (adj[v, ys] == 0))
+        hit = np.flatnonzero(ok)
+        if len(hit) == 0:
+            continue
+        k = hit[0]
+        if cross:
+            a2, b2 = int(a2[k]), int(b2[k])
+            new_edges = ((a1, b2), (a2, b1))
+            old_edges = ((a1, b1), (a2, b2))
+        else:
+            x, y = int(xs[k]), int(ys[k])
+            new_edges = ((u, x), (v, y))
+            old_edges = ((u, v), (x, y))
+        for (p, q) in old_edges:
+            adj[p, q] -= 1
+            if p != q:
+                adj[q, p] -= 1
             else:
-                if len({u, v, x, y}) < (3 if u == v else 4):
-                    continue
-                if u == x or v == y or adj[u, x] > 0 or adj[v, y] > 0:
-                    continue
-                if u == v and (adj[u, y] > 0 or x == y):
-                    # self-loop (u,u) + (x,y) -> (u,x),(u,y)
-                    continue
-                if u == v:
-                    new_edges = ((u, x), (u, y))
-                else:
-                    new_edges = ((u, x), (v, y))
-                old_edges = ((u, v), (x, y))
-            for (p, q) in old_edges:
-                adj[p, q] -= 1
-                if p != q:
-                    adj[q, p] -= 1
-                else:
-                    adj[p, q] -= 1          # a self-loop uses two stubs
-            for (p, q) in new_edges:
-                adj[p, q] += 1
-                adj[q, p] += 1
-            break
+                adj[p, q] -= 1          # a self-loop uses two stubs
+        for (p, q) in new_edges:
+            adj[p, q] += 1
+            adj[q, p] += 1
     else:
         spans.current().set(iterations=max_iter)
     # iteration budget exhausted: a cluster may be too dense for a simple
