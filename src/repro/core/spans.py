@@ -6,7 +6,8 @@ Two views of where a solve spends its time, both always on:
   ``time.perf_counter`` and keeps a ``Span`` record (name, start, end,
   parent span, root id, integer ``counts``) in a bounded in-memory
   buffer; ``records()`` copies it and ``clear()`` empties it.  Every span
-  of one ``run_sweeps`` or ``solve_batch`` call shares the root's id.
+  of one ``run_sweeps``, ``solve_batch`` or ``optimize`` call shares the
+  root's id.
   Each span also opens a ``jax.profiler.TraceAnnotation`` named
   ``repro.<name>``, so in a profiled run it lands in the profiler's host
   plane on the device trace's clock (with no profiler session that is a
@@ -28,6 +29,18 @@ Two views of where a solve spends its time, both always on:
   ``graphs.repair``   one multigraph repair; ``iterations`` (swap rounds
                       of a repair that returned), ``stalled`` (1 when the
                       stall break fired)
+  ``design.optimize`` one fleet search (root); ``rounds``, ``fleet``,
+                      ``runs``
+  ``design.propose``  one round's move kernels; ``proposals``,
+                      ``restarts`` (random restarts where no kernel applied)
+  ``design.rank``     one ranking execute, from the fleet's traffic to its
+                      bounds; ``lanes``, ``refilled`` (1 when the round
+                      reused the previous plan), ``lane_iters_used`` (each
+                      lane's own iterations, summed), ``lane_iters_run``
+                      (each lane counted at its chunk's longest: what the
+                      device ran)
+  ``design.certify``  the final certification execute; ``lanes``,
+                      ``lane_iters_used``, ``lane_iters_run``
   =================== ===================================================
 
 * **Device op scopes.**  The solvers wrap the APSP forward, the APSP

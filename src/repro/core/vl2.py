@@ -126,7 +126,10 @@ def rewired_vl2_topology(spec: VL2Spec, n_tor: int, seed: int,
     for i in range(n_tor):
         e1, e2 = endpoints[2 * i], endpoints[2 * i + 1]
         if e1 == e2:
-            alt = np.flatnonzero(endpoints != e1)
+            # trade only with an uplink not yet wired: a trade with an
+            # earlier ToR's endpoint would move its quota after the fact
+            # and wire a switch past its port count
+            alt = 2 * i + 2 + np.flatnonzero(endpoints[2 * i + 2:] != e1)
             if len(alt):
                 j = int(alt[rng.integers(len(alt))])
                 endpoints[2 * i + 1], endpoints[j] = endpoints[j], endpoints[2 * i + 1]

@@ -36,6 +36,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from repro.core import spans
 from repro.core import traffic as traffic_mod
 from repro.core.engine import DualEngine, _PlannedEngine, as_engine
 from repro.core.plan import BatchPlan
@@ -106,6 +107,21 @@ def _aggregate(vals: np.ndarray, agg: str) -> float:
     raise ValueError(f"unknown agg {agg!r}; expected 'min' or 'mean'")
 
 
+def _lane_iters(solved) -> dict[str, int]:
+    """Lane-iterations of one executed plan: ``lane_iters_used`` sums each
+    lane's own ``iterations``; ``lane_iters_run`` gives every lane its
+    chunk's longest, since a lane that stopped early rides along until
+    the last lane of its chunk stops."""
+    longest: dict[int, int] = {}
+    for s in solved:
+        c = s.meta["chunk"]
+        longest[c] = max(longest.get(c, 0), s.iterations)
+    return {"lanes": len(solved),
+            "lane_iters_used": sum(s.iterations for s in solved),
+            "lane_iters_run": sum(longest[s.meta["chunk"]] for s in solved)}
+
+
+@spans.span("design.optimize")
 def optimize(space: DesignSpace,
              demand_fn: Callable[[Any, int], np.ndarray] | None = None,
              *,
@@ -157,6 +173,7 @@ def optimize(space: DesignSpace,
     if unknown:
         raise ValueError(f"unknown move kernel(s) {unknown}; "
                          f"known: {sorted(MOVES)}")
+    spans.current().set(rounds=rounds, fleet=fleet, runs=runs)
     if demand_fn is None:
         demand_fn = lambda topo, s: traffic_mod.make(  # noqa: E731
             "permutation", topo.servers, s)
@@ -178,22 +195,27 @@ def optimize(space: DesignSpace,
         """ONE BatchPlan.execute over the cands × eval_seeds fleet;
         returns per-candidate lists of InstanceSolve (sample-major)."""
         nonlocal executes, search_plan
-        topos = [c.topo for c in cands for _ in eval_seeds]
-        dems = [demand_fn(c.topo, s) for c in cands for s in eval_seeds]
-        plan = None
-        if solver is None and search_plan is not None:
-            try:
-                plan = search_plan.refill(topos, dems)
-            except ValueError:
-                plan = None            # fleet shape drifted: re-plan
-        if plan is None:
-            plan = eng.plan(topos, dems)
-        if solver is None:
-            search_plan = plan
-        executes += 1
-        all_keys.update(plan.stats.compile_keys)
-        solved = plan.execute(solver=solver or eng.solver,
-                              **eng._solver_kw())
+        with spans.span("design.rank" if solver is None
+                        else "design.certify") as sp:
+            topos = [c.topo for c in cands for _ in eval_seeds]
+            dems = [demand_fn(c.topo, s) for c in cands for s in eval_seeds]
+            plan = None
+            if solver is None and search_plan is not None:
+                try:
+                    plan = search_plan.refill(topos, dems)
+                except ValueError:
+                    plan = None            # fleet shape drifted: re-plan
+            refilled = plan is not None
+            if plan is None:
+                plan = eng.plan(topos, dems)
+            if solver is None:
+                search_plan = plan
+                sp.set(refilled=refilled)
+            executes += 1
+            all_keys.update(plan.stats.compile_keys)
+            solved = plan.execute(solver=solver or eng.solver,
+                                  **eng._solver_kw())
+            sp.set(**_lane_iters(solved))
         k = len(eval_seeds)
         return [solved[i * k:(i + 1) * k] for i in range(len(cands))]
 
@@ -234,17 +256,21 @@ def optimize(space: DesignSpace,
     applicable = list(moves)
     for r in range(round0, round0 + rounds):
         proposals: list[Candidate] = []
-        for i in range(fleet):
-            parent = elites[i % len(elites)].cand
-            new = None
-            for _ in range(8):
-                name = applicable[int(rng.integers(len(applicable)))]
-                new = MOVES[name](parent, rng, space)
-                if new is not None:
-                    break
-            if new is None:     # no kernel applies: pure random restart
-                new = space.initial(int(rng.integers(1 << 31)))
-            proposals.append(new)
+        restarts = 0
+        with spans.span("design.propose") as sp:
+            for i in range(fleet):
+                parent = elites[i % len(elites)].cand
+                new = None
+                for _ in range(8):
+                    name = applicable[int(rng.integers(len(applicable)))]
+                    new = MOVES[name](parent, rng, space)
+                    if new is not None:
+                        break
+                if new is None:     # no kernel applies: pure random restart
+                    new = space.initial(int(rng.integers(1 << 31)))
+                    restarts += 1
+                proposals.append(new)
+            sp.set(proposals=len(proposals), restarts=restarts)
         scored = score_fleet(proposals, eval_seeds)
         merged = sorted(elites + scored, key=lambda e: -e.score)
         elites = merged[:elite]

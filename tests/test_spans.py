@@ -363,8 +363,43 @@ def test_device_readers_refuse_what_they_cannot_place(monkeypatch, metric,
     assert load_module("metrics", metric).read(run) is None
 
 
+def _counted(name, start, end, **counts):
+    return spans.Span(name, start, end, 0, None, 0, counts)
+
+
+DESIGN_SPANS = [
+    # a search in call 0 (10 s .. 12 s): a ranking execute, a round's
+    # moves, a refilled ranking execute, then the certification
+    _counted("design.rank", 10.0, 10.6, lanes=4, refilled=0,
+             lane_iters_used=300, lane_iters_run=400),
+    _counted("design.propose", 10.6, 10.7, proposals=2, restarts=0),
+    _counted("design.rank", 10.7, 11.2, lanes=4, refilled=1,
+             lane_iters_used=200, lane_iters_run=200),
+    _counted("design.certify", 11.2, 11.9, lanes=6, lane_iters_used=500,
+             lane_iters_run=600),
+    # set-up's warm-up and a search outside the calls: not counted
+    _counted("design.rank", 5.0, 6.0, lanes=4, lane_iters_used=100,
+             lane_iters_run=400),
+    _counted("design.propose", 12.5, 13.0, proposals=2, restarts=0),
+]
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("design_propose_pct", 100.0 * 0.1 / 6.0),
+    ("design_certify_pct", 100.0 * 0.7 / 6.0),
+    ("rank_lane_occupancy_pct", 100.0 * 500 / 600),
+])
+def test_design_readers_by_hand(monkeypatch, metric, want):
+    monkeypatch.setattr(spans, "_records", collections.deque(DESIGN_SPANS))
+    got = load_module("metrics", metric).read(_run("vl2-design"))
+    assert got == pytest.approx(want, rel=1e-12)
+
+
 @pytest.mark.parametrize("metric", ["plan_host_pct", "instance_build_pct",
-                                    "repair_pct", "setup_compile_s"])
+                                    "repair_pct", "setup_compile_s",
+                                    "design_propose_pct",
+                                    "design_certify_pct",
+                                    "rank_lane_occupancy_pct"])
 def test_host_readers_report_nothing_without_program_spans(monkeypatch,
                                                            metric):
     from bench import scopes

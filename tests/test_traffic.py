@@ -248,3 +248,23 @@ def test_every_pattern_shares_the_core_invariants(name, seed):
 def test_adversarial_pattern_requires_topology():
     with pytest.raises(ValueError, match="topo"):
         traffic.make("adversarial", np.full(6, 2), seed=0)
+
+
+@pytest.mark.parametrize("servers", [[5, 5, 5, 5], [20, 20, 0, 0, 0],
+                                     [1, 3, 0, 2]])
+def test_bench_all_to_all_sends_one_unit_per_server(servers):
+    """The benchmark's own pattern (``bench/traffic/all_to_all.py``): each
+    server sends one unit in total, split equally over every other
+    server; none to itself, and flows inside one switch stay off the
+    network."""
+    from bench.files import load_module
+    servers = np.asarray(servers)
+    dem = load_module("traffic", "all_to_all").demand(
+        servers, np.random.default_rng(0))
+    total = servers.sum()
+    intra = servers * (servers - 1) / (total - 1)
+    assert np.all(np.diag(dem) == 0.0)
+    np.testing.assert_allclose(dem.sum(axis=1) + intra, servers, rtol=1e-12)
+    np.testing.assert_allclose(dem.sum(axis=0) + intra, servers, rtol=1e-12)
+    u, v = 0, int(np.flatnonzero(servers)[-1])
+    assert dem[u, v] == pytest.approx(servers[u] * servers[v] / (total - 1))

@@ -52,6 +52,25 @@ def test_rewired_vl2_uses_same_equipment():
     assert ports_used <= (2 * n_tor + total_fabric_ports) + 1
 
 
+@pytest.mark.parametrize("spec,n_tor", [(vl2.VL2Spec(6, 6, 20), 12),
+                                         (vl2.VL2Spec(22, 22, 20), 173)])
+def test_rewired_vl2_keeps_every_switch_within_its_ports(spec, n_tor):
+    """Each ToR keeps its two uplinks and each other switch its quota of
+    them, so no switch is wired past its port count and every seed uses
+    the same ports: one idle port at most (an odd port count)."""
+    ports = vl2.FABRIC * np.concatenate([np.full(spec.n_agg, spec.d_a),
+                                         np.full(spec.n_core, spec.d_i)])
+    used = []
+    for seed in range(8):
+        topo = vl2.rewired_vl2_topology(spec, n_tor, seed)
+        attached = topo.cap.sum(axis=0)
+        assert np.all(attached[:n_tor] == 2 * vl2.FABRIC)
+        assert np.all(attached[n_tor:] <= ports)
+        assert (ports - attached[n_tor:]).sum() <= vl2.FABRIC
+        used.append(attached)
+    assert all(np.array_equal(u, used[0]) for u in used)
+
+
 def test_rewired_supports_at_least_as_many_tors():
     # paper ratio: 20 x 1G servers vs 2 x 10G uplinks (exactly balanced)
     spec20 = vl2.VL2Spec(d_a=4, d_i=4, servers_per_tor=20)
