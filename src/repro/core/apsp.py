@@ -49,6 +49,16 @@ mass drains onto the diagonal (path complete).  Consequences:
   enumerates predecessors from the ELL table (``O(N^2 d_max)`` per
   sweep) instead of walking dense N-chunks — the tie masks, counts, and
   routed masses are the same quantities, element for element;
+* both flavors sum the one-hop cotangent ``un[s, k]`` as one left fold
+  in ascending target ``t``.  The dense flavor adds one target's slice
+  at a time; the ELL flavor folds predecessor-major, one slot per
+  predecessor per step, so a hop takes as many steps as the most slots
+  any predecessor holds (the out-degree, on a symmetric fabric) rather
+  than one per target.  Skipping a target that does not list ``k`` is
+  exact: its term is ``+0.0`` or ``-0.0``, the accumulator starts at
+  ``+0.0``, and round-to-nearest makes a sum ``-0.0`` only when both
+  addends are, so the accumulator is never ``-0.0`` and adding a zero
+  to it changes no bit;
 * per-pair gradient mass is a unit flow routed on shortest paths (what
   the Frank-Wolfe primal oracle requires);
 * backward memory is ``O(N^2 * chunk)`` (t-chunked mask slabs) instead
@@ -276,6 +286,25 @@ def _sp_dag_grad(w: jax.Array, d: jax.Array, g: jax.Array) -> jax.Array:
     return dw.astype(w.dtype)
 
 
+def _fold_plan(ic: jax.Array, wc: jax.Array, m: int):
+    """Predecessor-major plan of one chunk's ELL slots ``(ic, wc)``, each
+    ``(c, d_max)``: ``slots[i, k]`` is the flat index of the ``i``-th slot
+    (ascending target) whose predecessor is ``k``, or -1 past the last;
+    ``steps`` is the most slots any ``k`` holds.  Pad slots key past the
+    last column and are never listed."""
+    c = ic.shape[0]
+    key = jnp.where(wc < _INF / 2, ic, m).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    cnt = jnp.zeros(m, jnp.int32).at[key].add(1, mode="drop")
+    k = key[order]
+    # a target lists k at most once, so a rank never reaches c
+    rank = jnp.arange(k.shape[0]) - (jnp.cumsum(cnt) - cnt)[
+        jnp.minimum(k, m - 1)]
+    slots = jnp.full((c, m), -1, jnp.int32).at[rank, k].set(
+        order, mode="drop")
+    return slots, cnt.max()
+
+
 def _sp_dag_grad_ell(w: jax.Array, d: jax.Array, g: jax.Array,
                      d_max: int) -> jax.Array:
     """ELL-aware flavor of :func:`_sp_dag_grad`: the one-hop walk
@@ -296,8 +325,8 @@ def _sp_dag_grad_ell(w: jax.Array, d: jax.Array, g: jax.Array,
     pad = (-n) % c
     if pad:
         # pad the TARGET axis only (predecessors stay the n real rows):
-        # padded rows get idx 0 / wgt _INF, so they tie nowhere and
-        # scatter +0.0 onto column 0
+        # padded rows get idx 0 / wgt _INF, so they tie nowhere and the
+        # fold never lists their slots
         idx = jnp.pad(idx, ((0, pad), (0, 0)))
         wgt = jnp.pad(wgt, ((0, pad), (0, 0)), constant_values=_INF)
         df_t = jnp.pad(df, ((0, 0), (0, pad)), constant_values=_INF)
@@ -306,6 +335,10 @@ def _sp_dag_grad_ell(w: jax.Array, d: jax.Array, g: jax.Array,
         df_t = df
     m = n + pad
     diag = jnp.arange(n)[:, None] == jnp.arange(m)[None, :]
+    # the fold's plan per chunk, fixed for the whole backward: slots in
+    # predecessor-major order, ascending target within each predecessor
+    slots, steps = jax.vmap(_fold_plan, in_axes=(0, 0, None))(
+        idx.reshape(m // c, c, d_max), wgt.reshape(m // c, c, d_max), m)
 
     def one_hop(u, dw_ell):
         def chunk_body(j, acc):
@@ -313,7 +346,7 @@ def _sp_dag_grad_ell(w: jax.Array, d: jax.Array, g: jax.Array,
             uc = jax.lax.dynamic_slice_in_dim(u, t0, c, axis=1)
 
             def relax(acc):
-                un, dwn = acc
+                un_t, dwn = acc
                 ic = jax.lax.dynamic_slice_in_dim(idx, t0, c, axis=0)
                 wc = jax.lax.dynamic_slice_in_dim(wgt, t0, c, axis=0)
                 dc = jax.lax.dynamic_slice_in_dim(df_t, t0, c, axis=1)
@@ -328,28 +361,36 @@ def _sp_dag_grad_ell(w: jax.Array, d: jax.Array, g: jax.Array,
                 mf = mask.astype(jnp.float32)
                 mf = mf / jnp.maximum(mf.sum(axis=2, keepdims=True), 1.0)
                 mf = mf * uc[:, :, None]
-                # cotangent one hop back, one ascending target at a time
-                # (mirrors the dense adjoint's accumulation order so the
-                # two stay bit-identical; within one target each real k
-                # holds exactly one slot, and pad slots add exact +0.0)
-                un = jax.lax.fori_loop(
-                    0, c,
-                    lambda tc, acc: acc.at[
-                        :, jax.lax.dynamic_index_in_dim(
-                            ic, tc, axis=0, keepdims=False)].add(
-                        jax.lax.dynamic_index_in_dim(
-                            mf, tc, axis=1, keepdims=False)),
-                    un)
+                # cotangent one hop back, folded predecessor-major: step
+                # i adds, for every k at once, the slab row of k's i-th
+                # slot (ascending target), so each un[s, k] is the same
+                # left fold in ascending t as a target-by-target scatter,
+                # in max-slots-per-k steps instead of c
+                rows = jnp.moveaxis(mf, 0, 2)              # (c, d_max, n)
+                tab = slots[j]
+
+                def fold(i, un_t):
+                    slot = tab[i]
+                    live = slot >= 0
+                    slot = jnp.maximum(slot, 0)
+                    row = rows[slot // d_max, slot % d_max]    # (m, n)
+                    return un_t + jnp.where(live[:, None], row, 0.0)
+
+                un_t = jax.lax.fori_loop(0, steps[j], fold, un_t)
                 dep = jax.lax.dynamic_slice_in_dim(dwn, t0, c, axis=0)
                 dwn = jax.lax.dynamic_update_slice_in_dim(
                     dwn, dep + mf.sum(axis=0), t0, axis=0)
-                return un, dwn
+                return un_t, dwn
 
             return jax.lax.cond(jnp.any(uc != 0.0), relax,
                                 lambda acc: acc, acc)
 
-        return jax.lax.fori_loop(0, m // c, chunk_body,
-                                 (jnp.zeros_like(u), dw_ell))
+        # the fold runs on un transposed, (k, s), so each step reads
+        # whole slab rows
+        un_t, dw_ell = jax.lax.fori_loop(
+            0, m // c, chunk_body,
+            (jnp.zeros((m, n), jnp.float32), dw_ell))
+        return un_t.T, dw_ell
 
     def cond(carry):
         u, _, it = carry

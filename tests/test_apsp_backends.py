@@ -378,6 +378,69 @@ def test_sp_dag_grad_padded_chunks_bit_identical(monkeypatch):
         "ELL adjoint changed bits under chunk padding"
 
 
+def _w_hub(n, seed):
+    """A ring 0 -> 1 -> ... -> n-1 -> 0 plus a hub 0 -> t for every t:
+    predecessor 0 holds a slot in n - 1 target rows while the table is
+    only d_max = 2 wide."""
+    rng = np.random.default_rng(seed)
+    w = np.full((n, n), _INF, np.float32)
+    t = np.arange(n)
+    w[t, (t + 1) % n] = _quantize(rng.uniform(1.0, 4.0, n))
+    # short hub edges put the hub on most shortest paths, so its column
+    # of the one-hop cotangent sums many rounded terms
+    w[0, 1:] = _quantize(rng.uniform(0.5, 1.5, n - 1))
+    np.fill_diagonal(w, 0.0)
+    return jnp.asarray(w)
+
+
+def _signed_cotangent(d, seed):
+    """Unquantized cotangents of both signs: every add rounds, so only
+    the same accumulation order gives the same bits."""
+    n = d.shape[0]
+    g = np.random.default_rng(seed).normal(size=(n, n)).astype(np.float32)
+    return jnp.where(d < _INF / 2, jnp.asarray(g), 0.0)
+
+
+_FOLD_CASES = ("hub", "signed-random-sparse", "signed-rrg-unit",
+               "signed-two-cluster", "split-chunks", "vmap-lanes")
+
+
+@pytest.mark.parametrize("case", _FOLD_CASES)
+def test_ell_fold_matches_dense_adjoint(case, monkeypatch):
+    """The ELL adjoint folds each predecessor's slots in ascending target
+    order; it must give the dense adjoint's bits when a predecessor feeds
+    more targets than the table is wide, under signed unquantized
+    cotangents, when a predecessor's slots span two chunks, and under
+    vmap over lanes whose largest slot counts differ."""
+    if case == "vmap-lanes":
+        ws = [_w_hub(20, 3), _w_topo(random_regular_graph(20, 4, seed=4))]
+        d_max = max(_ell_d_max(w) for w in ws)
+        ds = [apsp(w, "squaring") for w in ws]
+        gs = [_signed_cotangent(d, 5 + i) for i, d in enumerate(ds)]
+        ref = [np.asarray(apsp_mod._sp_dag_grad(*a)) for a in zip(ws, ds, gs)]
+        got = np.asarray(jax.vmap(
+            lambda w, d, g: apsp_mod._sp_dag_grad_ell(w, d, g, d_max))(
+                jnp.stack(ws), jnp.stack(ds), jnp.stack(gs)))
+        for i in range(2):
+            assert np.array_equal(got[i], ref[i]), f"lane {i}"
+        return
+    w = (_w_hub(32, 1) if case in ("hub", "split-chunks")
+         else _w_cases()[case.removeprefix("signed-")])
+    n = w.shape[0]
+    d_max = _ell_d_max(w)
+    d = apsp(w, "squaring")
+    g = _signed_cotangent(d, 9)
+    ref = np.asarray(apsp_mod._sp_dag_grad(w, d, g))
+    if case == "split-chunks":
+        # c = 20 on n = 32: the hub's 31 slots fall in two chunks, the
+        # second padded by 8 targets
+        monkeypatch.setattr(apsp_mod, "_BWD_ELEMS", n * d_max * 20)
+        assert apsp_mod._bwd_chunk(n, d_max) == 20
+    got = np.asarray(apsp_mod._sp_dag_grad_ell(w, d, g, d_max))
+    assert np.array_equal(got, ref)
+    assert np.any(ref != 0.0)
+
+
 def test_ell_bf_vmaps_like_dense_backends():
     ws = jnp.stack([_w_topo(random_regular_graph(16, 4, seed=s))
                     for s in range(3)])
