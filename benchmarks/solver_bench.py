@@ -195,7 +195,8 @@ def run_smoke() -> list[dict]:
     engines = [
         get_engine("exact"),
         get_engine("dual", iters=60, tol=1e-3),
-        get_engine("dual-pallas", iters=60, tol=1e-3, interpret=True),
+        get_engine("dual", backend="squaring-pallas", iters=60, tol=1e-3,
+                   interpret=True),
         get_engine("primal", iters=60, tol=1e-3),
         get_engine("certified", iters=60, tol=1e-3),
     ]
@@ -219,6 +220,7 @@ def run_smoke() -> list[dict]:
                 "certified smoke must produce finite brackets"
         plan = getattr(eng, "last_plan", None)
         rows.append({"figure": "solver_smoke", "engine": eng.name,
+                     "backend": getattr(eng, "backend", None),
                      "instances": len(out), "wall_s": time.time() - t0,
                      "devices": plan.devices if plan else 1,
                      "chunks": plan.chunks if plan else 0,

@@ -13,7 +13,7 @@ a.  Paper-width certification: random regular graphs of 640 switches,
     resolves to the ``ell-bf`` backend.  Each lane must satisfy
     0 < lb <= ub and lb <= the Theorem-1 bound.
 b.  The same instances on ``backend="blocked-fw"`` (certified) and on
-    ``"dual-pallas"`` (upper bound): no lower bound of any backend may
+    ``"dual"`` on ``backend="squaring-pallas"`` (upper bound): no lower bound of any backend may
     exceed an upper bound of another.
 c.  A Fig. 6-size design sweep: 3 server splits x 4 cross-cluster biases
     x 20 runs = 240 lanes of a 30-switch two-class fabric, one plan.
@@ -161,12 +161,14 @@ def phase_certify(aot, topos, dems, ports: int, **engine_kw):
 def phase_dual_pallas(aot, topos, dems, lbs):
     """Phase b: the squaring-pallas dual bound sits above every lb."""
     from repro.core import get_engine
-    eng = get_engine("dual-pallas", devices=1, aot_cache=aot)
+    eng = get_engine("dual", backend="squaring-pallas", devices=1,
+                     aot_cache=aot)
     results, facts = solve(eng, topos, dems)
     ubs = [r.throughput for r in results]
     for i, (ub, lb) in enumerate(zip(ubs, lbs)):
         check(0.0 < ub < float("inf") and lb <= ub * (1 + REL),
-              f"dual-pallas lane {i}: ub {ub} below certified lb {lb}")
+              f"squaring-pallas dual lane {i}: ub {ub} below certified "
+              f"lb {lb}")
     facts["ub"] = ubs
     return facts
 

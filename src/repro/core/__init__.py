@@ -2,7 +2,8 @@
 
 Modules: graphs (Topology + generation), traffic (named demand patterns),
 engine (unified ThroughputEngine registry + declarative sweeps), plan
-(BatchPlan: bucketed/chunked/device-sharded batch execution core), lp (exact
+(BatchPlan: bucketed/chunked/device-sharded batch execution core), batch
+(the batched solvers' one table and runner), lp (exact
 HiGHS max-concurrent-flow), mcf (JAX dual solver on min-plus APSP: certified
 upper bounds), primal (Frank-Wolfe shortest-path-routing primal solver:
 certified lower bounds, fused lb/ub brackets), bounds (Thm 1 / Cerf d* /

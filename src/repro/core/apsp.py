@@ -8,7 +8,7 @@ pass dispatches on the backend registry:
 * ``"squaring"``        — pure-jnp repeated (min,+) squaring (the legacy
   default path; ``O(N^3 log N)`` work, ``O(N^3)`` broadcast per step);
 * ``"squaring-pallas"`` — repeated squaring on the Pallas tropical-matmul
-  kernel (what ``use_pallas=True`` historically selected);
+  kernel;
 * ``"blocked-fw"``      — blocked Floyd-Warshall (``repro.kernels.fw``):
   one ``O(N^3)`` pass, ``O(N^2)`` live memory.  Compiled Pallas tiles on
   TPU (or with explicit ``interpret=True``); a ``lax.fori`` Floyd-Warshall
@@ -28,10 +28,9 @@ pass dispatches on the backend registry:
   "auto")`` never goes sparse implicitly — density is a host-side fact
   the solvers compute from capacity patterns (``graphs.degree_stats``).
 
-``normalize_backend`` maps the legacy ``use_pallas`` booleans threaded
-through ``mcf``/``primal``/``engine`` onto registry names, so existing
-call sites (``get_engine("dual-pallas")``, ``use_pallas=True``) keep
-working unchanged.
+``normalize_backend`` checks a name against the registry (``None``
+means ``"auto"``); ``resolve_backend_density`` is the solvers' host-side
+density step.
 
 **The subgradient seam.**  All backends share ONE ``jax.custom_vjp``
 backward: a Bellman fixed-point adjoint that only needs the saved
@@ -75,13 +74,16 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.core.graphs import degree_stats
 from repro.core.spans import scoped
 from repro.kernels import ell as kell
 from repro.kernels import fw as kfw
 from repro.kernels import ops as kops
 
-__all__ = ["apsp", "normalize_backend", "resolve_backend", "BACKENDS",
+__all__ = ["apsp", "normalize_backend", "resolve_backend",
+           "resolve_backend_density", "BACKENDS",
            "AUTO_THRESHOLD", "SPARSE_THRESHOLD", "_INF"]
 
 _INF = 1.0e18   # non-edge sentinel: survives one add in float32 headroom
@@ -93,15 +95,11 @@ _FW_TILE = 128           # Pallas tile for the blocked-fw flavor
 _BWD_ELEMS = 1 << 25     # float budget for one backward mask slab
 
 
-def normalize_backend(backend: str | bool | None = None,
-                      use_pallas: bool = False) -> str:
-    """Map a backend spec (registry name, legacy ``use_pallas`` bool, or
-    None) to a registry name.  ``None`` defers to ``use_pallas`` for
-    compatibility: True -> "squaring-pallas", False -> "auto"."""
+def normalize_backend(backend: str | None = None) -> str:
+    """A registry name for ``backend``: ``None`` -> ``"auto"``; anything
+    outside ``BACKENDS`` raises ``ValueError``."""
     if backend is None:
-        return "squaring-pallas" if use_pallas else "auto"
-    if isinstance(backend, bool):   # legacy positional use_pallas slot
-        return "squaring-pallas" if backend else "squaring"
+        return "auto"
     if backend not in BACKENDS:
         raise ValueError(f"unknown APSP backend {backend!r}; "
                          f"known: {BACKENDS}")
@@ -121,6 +119,34 @@ def resolve_backend(backend: str, n: int, *,
             return "ell-bf"
         return "blocked-fw" if n >= AUTO_THRESHOLD else "squaring"
     return backend
+
+
+def resolve_backend_density(backend: str, caps, *, n: int,
+                            d_max: int | None = None,
+                            mean_degree: float | None = None,
+                            ) -> tuple[str, int | None]:
+    """Host-side density resolution shared by the dual/primal solvers:
+    decide whether ``backend`` lands on ``"ell-bf"`` and with what table
+    width.  Returns ``(backend, d_max)`` where ``d_max`` is None unless
+    the resolved backend is ``"ell-bf"``.
+
+    Dense resolutions pass ``backend`` through UNCHANGED (``"auto"``
+    stays ``"auto"``), so dense solves keep their existing jit/AOT cache
+    keys.  ``caps`` (an instance or stacked batch of capacity matrices)
+    is only scanned when the caller did not already supply the stats —
+    ``BatchPlan`` passes per-chunk hints computed before padding."""
+    if backend not in ("auto", "ell-bf"):
+        return backend, None
+    if d_max is None or (backend == "auto" and mean_degree is None):
+        stats_d_max, stats_mean = degree_stats(np.asarray(caps))
+        if d_max is None:
+            d_max = stats_d_max
+        if mean_degree is None:
+            mean_degree = stats_mean
+    resolved = resolve_backend(backend, n, mean_degree=mean_degree)
+    if resolved != "ell-bf":
+        return backend, None
+    return "ell-bf", max(1, int(d_max))
 
 
 def _squaring_steps(n: int) -> int:

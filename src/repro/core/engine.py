@@ -17,8 +17,9 @@ This module gives that one API:
   is sharded across ``devices`` local devices, and all chunks dispatch
   asynchronously with ONE host sync at the end — a whole mixed-size sweep
   compiles once per (bucket, chunk-shape) and keeps every device busy.
-  ``use_pallas=True`` routes the (min,+) APSP inner loop through the
-  Pallas TPU kernel; ``interpret=None`` auto-detects
+  ``backend`` picks the APSP backend (``repro.core.apsp``;
+  ``"squaring-pallas"`` runs the Pallas (min,+) kernel at every size);
+  ``interpret=None`` auto-detects
   compiled-vs-interpreter from the JAX backend.  ``tol > 0`` enables
   convergence-based early stopping.
 * ``PrimalEngine`` — the Frank–Wolfe primal solver (``repro.core.primal``);
@@ -33,8 +34,8 @@ This module gives that one API:
   k-shortest-path multipath routing, each carrying the ideal bracket's
   upper bound and ``meta["ideal_gap_pct"]`` (the certified price of the
   routing restriction).
-* ``get_engine("exact" | "dual" | "dual-pallas" | "primal" | "certified" |
-  "ecmp" | "ksp" | "auto")`` — string registry; ``as_engine``
+* ``get_engine("exact" | "dual" | "primal" | "certified" | "ecmp" |
+  "ksp" | "auto" | "adversarial")`` — string registry; ``as_engine``
   additionally passes engine instances through, so every driver accepts
   either.
 * ``Sweep`` / ``run_sweep`` / ``run_sweeps`` — declarative (xs × runs)
@@ -201,8 +202,8 @@ class _PlannedEngine:
     batches = True
     solver: str = "dual"
 
-    def __init__(self, use_pallas: bool = False, iters: int = 800,
-                 lr: float = 0.08, tol: float = 0.0, check_every: int = 25,
+    def __init__(self, iters: int = 800, lr: float = 0.08,
+                 tol: float = 0.0, check_every: int = 25,
                  bucket: str | int | None = "pow2",
                  interpret: bool | None = None,
                  devices: int | None = None,
@@ -213,7 +214,6 @@ class _PlannedEngine:
                  aot_cache: bool | str | None = None,
                  d_max: int | None = None,
                  max_rounds: int | None = None):
-        self.use_pallas = use_pallas
         self.iters = iters
         self.lr = lr
         self.tol = tol
@@ -227,9 +227,8 @@ class _PlannedEngine:
             raise ValueError("on_disconnected must be None, 'raise' or "
                              f"'drop', got {on_disconnected!r}")
         self.on_disconnected = on_disconnected
-        # backend: ApspBackend registry name; None defers to the legacy
-        # use_pallas flag (True -> "squaring-pallas", False -> "auto")
-        self.backend = apsp_mod.normalize_backend(backend, use_pallas)
+        # backend: ApspBackend registry name (None -> "auto")
+        self.backend = apsp_mod.normalize_backend(backend)
         # coarsen: contract server leaf nodes (Topology.server_nodes) onto
         # their switches before planning, so plan lanes carry switch-only
         # graphs with lifted demand (exact; see Topology.coarsen)
@@ -352,12 +351,8 @@ class DualEngine(_PlannedEngine):
     ``iterations`` and ``final_ratio`` (the last iterate's bound — its
     distance from ``throughput`` is a convergence probe)."""
 
+    name = "dual"
     solver = "dual"
-
-    def __init__(self, use_pallas: bool = False, **kw):
-        super().__init__(use_pallas=use_pallas, **kw)
-        self.name = ("dual-pallas" if self.backend == "squaring-pallas"
-                     else "dual")
 
     def solve(self, topo, dem) -> ThroughputResult:
         topo, dem, frac, short = self._solve_preprocessed(topo, dem)
@@ -616,7 +611,6 @@ class AdversarialEngine:
 ENGINES: dict[str, Callable[[], ThroughputEngine]] = {
     "exact": ExactLPEngine,
     "dual": DualEngine,
-    "dual-pallas": lambda **kw: DualEngine(use_pallas=True, **kw),
     "primal": PrimalEngine,
     "certified": CertifiedEngine,
     "ecmp": EcmpEngine,

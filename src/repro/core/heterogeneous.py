@@ -10,7 +10,7 @@ All sweeps are declarative ``engine.Sweep``s executed by
 through one ``solve_batch`` call, and the grid drivers (``combined_sweep``,
 ``line_speed_sweep``) route ALL of their member sweeps through a single
 ``run_sweeps`` call — one ``BatchPlan`` for the whole figure family on a
-batching engine (``get_engine("dual")`` / ``"dual-pallas"``), instead of
+batching engine (``get_engine("dual")``, on any ``backend``), instead of
 one small batch per grid cell.  ``cross_cluster_sweep_item`` exposes the
 (sweep, build_fn) building block so figure harnesses (e.g. Fig. 7's three
 panels) can pool even more sweeps into one plan.  The ``engine`` argument

@@ -10,8 +10,7 @@ minimise the log-ratio with Adam in log-length space.  dist_l is all-pairs
 shortest paths via ``repro.core.apsp`` — an ``ApspBackend`` registry
 (``"squaring" | "squaring-pallas" | "blocked-fw" | "auto"``) whose shared
 custom VJP yields shortest-path-DAG subgradients identically on every
-backend.  ``backend`` selects it; the legacy ``use_pallas`` flag keeps
-working and maps onto the registry (True -> "squaring-pallas").
+backend.  ``backend`` selects it.
 
 This is the paper's CPLEX replacement that actually scales: it is pure
 dense linear algebra, jit/vmap-able over topology batches (the paper's "20
@@ -51,9 +50,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import aotcache
 from repro.core import apsp as apsp_mod
-from repro.core.apsp import _INF, normalize_backend
+from repro.core import batch
+from repro.core.apsp import _INF, normalize_backend, resolve_backend_density
 from repro.core.graphs import (Topology, as_cap, connected_components,
                                degree_stats)
 from repro.core.spans import scoped
@@ -62,7 +61,6 @@ from repro.kernels import ops as kops
 __all__ = ["DualResult", "DualBatchResult", "DualDemgradBatchResult",
            "apsp", "solve_dual", "solve_dual_batch",
            "solve_dual_demgrad_batch", "aspl", "drop_disconnected",
-           "jit_cache_size", "compile_cache_sizes",
            "resolve_backend_density", "_INF"]
 
 
@@ -126,7 +124,7 @@ class DualDemgradBatchResult:
         return len(self.throughput_ub)
 
 
-def apsp(w: jax.Array, backend: str | bool | None = "auto",
+def apsp(w: jax.Array, backend: str | None = "auto",
          interpret: bool | None = None, d_max: int | None = None,
          max_rounds: int | None = None) -> jax.Array:
     """All-pairs shortest paths of a weighted adjacency matrix.  ``w``:
@@ -135,9 +133,8 @@ def apsp(w: jax.Array, backend: str | bool | None = "auto",
     same unit; unreachable pairs stay ~``_INF`` (compare against
     ``_INF / 2``, never equality).
 
-    ``backend`` names an ``ApspBackend`` (see ``repro.core.apsp``);
-    legacy boolean ``use_pallas`` values are accepted in the same slot
-    (True -> "squaring-pallas").  ``d_max``/``max_rounds`` are the
+    ``backend`` names an ``ApspBackend`` (see ``repro.core.apsp``).
+    ``d_max``/``max_rounds`` are the
     ``"ell-bf"`` statics (table width / relaxation-round cap).
     Differentiable on every backend — the shared VJP is the
     shortest-path-DAG subgradient both solvers consume."""
@@ -145,37 +142,8 @@ def apsp(w: jax.Array, backend: str | bool | None = "auto",
                          d_max, max_rounds)
 
 
-def resolve_backend_density(backend: str, caps, *, n: int,
-                            d_max: int | None = None,
-                            mean_degree: float | None = None,
-                            ) -> tuple[str, int | None]:
-    """Host-side density resolution shared by the dual/primal solvers:
-    decide whether ``backend`` lands on ``"ell-bf"`` and with what table
-    width.  Returns ``(backend, d_max)`` where ``d_max`` is None unless
-    the resolved backend is ``"ell-bf"``.
-
-    Dense resolutions pass ``backend`` through UNCHANGED (``"auto"``
-    stays ``"auto"``), so dense solves keep their existing jit/AOT cache
-    keys.  ``caps`` (an instance or stacked batch of capacity matrices)
-    is only scanned when the caller did not already supply the stats —
-    ``BatchPlan`` passes per-chunk hints computed before padding."""
-    if backend not in ("auto", "ell-bf"):
-        return backend, None
-    if d_max is None or (backend == "auto" and mean_degree is None):
-        stats_d_max, stats_mean = degree_stats(np.asarray(caps))
-        if d_max is None:
-            d_max = stats_d_max
-        if mean_degree is None:
-            mean_degree = stats_mean
-    resolved = apsp_mod.resolve_backend(backend, n, mean_degree=mean_degree)
-    if resolved != "ell-bf":
-        return backend, None
-    return "ell-bf", max(1, int(d_max))
-
-
 def aspl(cap: Topology | np.ndarray | jax.Array,
          dem: np.ndarray | jax.Array | None = None,
-         use_pallas: bool = False,
          interpret: bool | None = None,
          on_disconnected: str = "raise", *,
          backend: str | None = None) -> float:
@@ -202,8 +170,8 @@ def aspl(cap: Topology | np.ndarray | jax.Array,
     n = cap_host.shape[0]
     # hop-metric probes over big degree-bounded graphs are exactly where
     # the sparse backend pays off — resolve density host-side
-    bk, d_max = resolve_backend_density(
-        normalize_backend(backend, use_pallas), cap_host, n=n)
+    bk, d_max = resolve_backend_density(normalize_backend(backend),
+                                        cap_host, n=n)
     cap = jnp.asarray(cap_host, jnp.float32)
     w = jnp.where(cap > 0, 1.0, _INF)
     w = jnp.where(jnp.eye(n, dtype=bool), 0.0, w)
@@ -378,14 +346,7 @@ def _solve_one_demgrad(cap: jax.Array, dem: jax.Array, n_valid: jax.Array,
     return best, final_ratio, it, g
 
 
-# the solver statics — all compile-key material, including the ell-bf
-# table width (d_max) and relaxation-round cap (max_rounds), which the
-# AOT cache keys on via the static_kw repr
-_STATIC = ("iters", "check_every", "backend", "interpret", "d_max",
-           "max_rounds")
-
-
-@functools.partial(jax.jit, static_argnames=_STATIC)
+@functools.partial(jax.jit, static_argnames=batch._STATIC)
 def _solve(cap, dem, n_valid, lr_peak, tol, *, iters, check_every,
            backend, interpret, d_max=None, max_rounds=None):
     return _solve_one(cap, dem, n_valid, lr_peak, tol, iters=iters,
@@ -394,63 +355,9 @@ def _solve(cap, dem, n_valid, lr_peak, tol, *, iters, check_every,
                       max_rounds=max_rounds)
 
 
-def _solve_batch_impl(caps, dems, n_valid, lr_peak, tol, *, iters,
-                      check_every, backend, interpret, d_max=None,
-                      max_rounds=None):
-    fn = functools.partial(_solve_one, iters=iters, check_every=check_every,
-                           backend=backend, interpret=interpret,
-                           d_max=d_max, max_rounds=max_rounds)
-    return jax.vmap(fn, in_axes=(0, 0, 0, None, None))(
-        caps, dems, n_valid, lr_peak, tol)
-_solve_batch = jax.jit(_solve_batch_impl, static_argnames=_STATIC)
-# the planner owns its device buffers, so it donates caps/dems back to XLA;
-# kept as a separate entry point so user-passed arrays are never invalidated
-_solve_batch_donated = jax.jit(_solve_batch_impl, static_argnames=_STATIC,
-                               donate_argnums=(0, 1))
-
-
-def _solve_demgrad_batch_impl(caps, dems, n_valid, lr_peak, tol, *, iters,
-                              check_every, backend, interpret, d_max=None,
-                              max_rounds=None):
-    fn = functools.partial(_solve_one_demgrad, iters=iters,
-                           check_every=check_every, backend=backend,
-                           interpret=interpret, d_max=d_max,
-                           max_rounds=max_rounds)
-    return jax.vmap(fn, in_axes=(0, 0, 0, None, None))(
-        caps, dems, n_valid, lr_peak, tol)
-_solve_demgrad_batch = jax.jit(_solve_demgrad_batch_impl,
-                               static_argnames=_STATIC)
-_solve_demgrad_batch_donated = jax.jit(_solve_demgrad_batch_impl,
-                                       static_argnames=_STATIC,
-                                       donate_argnums=(0, 1))
-
-
-def jit_cache_size(*fns) -> int | None:
-    """Total compiled-program count of the given jitted callables (one per
-    distinct (shape, static-arg) combination), or ``None`` (not 0 — callers
-    must not mistake "unavailable" for "no compiles") if the installed jax
-    does not expose ``_cache_size``, which is a private API.  Shared by
-    every solver backend's ``compile_cache_sizes``."""
-    sizes = [getattr(fn, "_cache_size", None) for fn in fns]
-    if not all(callable(s) for s in sizes):
-        return None
-    return sum(s() for s in sizes)
-
-
-def compile_cache_sizes() -> dict[str, int | None]:
-    """Compiled program variants per solver entry point.  Benchmarks report
-    deltas of this to show "one compile per bucket"."""
-    return {"solve": jit_cache_size(_solve),
-            "solve_batch": jit_cache_size(_solve_batch,
-                                          _solve_batch_donated),
-            "solve_demgrad_batch": jit_cache_size(
-                _solve_demgrad_batch, _solve_demgrad_batch_donated)}
-
-
 def solve_dual(cap: Topology | np.ndarray, dem: np.ndarray, *,
                iters: int = 800, lr: float = 0.08, tol: float = 0.0,
-               check_every: int = 25, use_pallas: bool = False,
-               interpret: bool | None = None,
+               check_every: int = 25, interpret: bool | None = None,
                backend: str | None = None, aot=None,
                d_max: int | None = None,
                max_rounds: int | None = None) -> DualResult:
@@ -461,16 +368,15 @@ def solve_dual(cap: Topology | np.ndarray, dem: np.ndarray, *,
     dimensionless per-unit-demand rate.  ``iters`` caps the descent;
     ``tol > 0`` stops early once the bound's relative improvement per
     ``check_every``-step window drops below it.  ``backend`` picks the
-    APSP backend (``repro.core.apsp.BACKENDS``; default auto, with
-    ``use_pallas=True`` kept as an alias for "squaring-pallas").  ``aot``
+    APSP backend (``repro.core.apsp.BACKENDS``; default auto).  ``aot``
     is accepted for signature parity with the batch entry point; the
     persistent compile cache only serves batched plans."""
     del aot   # single solves always JIT (plan lanes are the hot path)
     interpret = kops.resolve_interpret(interpret)
     cap_host = as_cap(cap)
     backend, d_max = resolve_backend_density(
-        normalize_backend(backend, use_pallas), cap_host,
-        n=cap_host.shape[0], d_max=d_max)
+        normalize_backend(backend), cap_host, n=cap_host.shape[0],
+        d_max=d_max)
     capj = jnp.asarray(cap_host, jnp.float32)
     best, final, it = _solve(
         capj, jnp.asarray(dem, jnp.float32), jnp.int32(capj.shape[0]),
@@ -480,86 +386,23 @@ def solve_dual(cap: Topology | np.ndarray, dem: np.ndarray, *,
     return DualResult(float(best), float(final), int(it))
 
 
-def solve_dual_batch(caps, dems, *, n_valid=None, iters: int = 800,
-                     lr: float = 0.08, tol: float = 0.0,
-                     check_every: int = 25, use_pallas: bool = False,
-                     interpret: bool | None = None,
-                     backend: str | None = None, aot=None,
-                     sharding=None, donate: bool = False,
-                     block: bool = True, d_max: int | None = None,
-                     mean_degree: float | None = None,
-                     max_rounds: int | None = None) -> DualBatchResult:
+
+
+def solve_dual_batch(caps, dems, **kw) -> DualBatchResult:
     """Batched solve over stacked [R, N, N] topologies/demands (the paper's
-    '20 runs per data point' in a single vmapped program).  ``caps`` may be a
-    stacked array or a sequence of Topologies/matrices of equal size; an
-    empty sequence returns an empty ``DualBatchResult``.
+    '20 runs per data point' in a single vmapped program); the keywords
+    and their defaults are ``repro.core.batch.run``'s.
 
     ``n_valid`` ([R] ints) marks how many leading nodes of each instance are
     real; the rest are padding (zero capacity/demand) and are masked out of
     the dual ratio.  Size-heterogeneous batches are padded into buckets and
     chunks by ``repro.core.plan.BatchPlan`` (which ``DualEngine.solve_batch``
     delegates to) — one compiled program per (bucket, chunk-shape).
-
-    ``sharding`` (a ``jax.sharding.Sharding``, normally ``NamedSharding(mesh,
-    P("batch"))`` over a 1-D mesh) commits the batch axis across devices; the
-    batch dimension must then be a device-count multiple.  ``donate=True``
-    hands the device input buffers back to XLA (only safe when the caller
-    does not reuse ``caps``/``dems`` afterwards).  ``block=False`` skips the
-    host transfer and returns in-flight device arrays — callers sync with
-    ``jax.block_until_ready`` (what ``BatchPlan.execute`` does once over all
-    of its chunks).
-
-    ``backend`` selects the APSP backend (see ``repro.core.apsp``); ``aot``
-    takes a ``repro.core.aotcache.AotCache`` to serve this chunk shape from
-    the persistent ahead-of-time compile cache (single-device plans only;
-    any cache failure falls back to plain JIT).
     """
-    interpret = kops.resolve_interpret(interpret)
-    backend = normalize_backend(backend, use_pallas)
-    if len(caps) != len(dems):
-        raise ValueError(f"caps ({len(caps)}) and dems ({len(dems)}) "
-                         "must have equal length")
-    if len(caps) == 0:
-        return DualBatchResult(np.zeros(0, np.float32),
-                               np.zeros(0, np.float32), np.zeros(0, np.int32))
-    if not isinstance(caps, (np.ndarray, jax.Array)):
-        caps = np.stack([as_cap(c) for c in caps])
-    if not isinstance(dems, (np.ndarray, jax.Array)):
-        dems = np.stack([np.asarray(d) for d in dems])
-    if n_valid is None:
-        n_valid = np.full(caps.shape[0], caps.shape[1], np.int32)
-    backend, d_max = resolve_backend_density(
-        backend, caps, n=caps.shape[1], d_max=d_max,
-        mean_degree=mean_degree)
-    capj = jnp.asarray(caps, jnp.float32)
-    demj = jnp.asarray(dems, jnp.float32)
-    nvj = jnp.asarray(n_valid, jnp.int32)
-    if sharding is not None:
-        capj, demj, nvj = jax.device_put((capj, demj, nvj), sharding)
-    fn = _solve_batch_donated if donate else _solve_batch
-    args = (capj, demj, nvj, jnp.float32(lr), jnp.float32(tol))
-    static_kw = dict(iters=iters, check_every=check_every,
-                     backend=backend, interpret=interpret,
-                     d_max=d_max, max_rounds=max_rounds)
-    best, final, it = aotcache.dispatch(
-        fn, ("dual", "donated" if donate else "plain"), args, static_kw,
-        aot=aot, sharding=sharding)
-    if not block:
-        return DualBatchResult(best, final, it)
-    return DualBatchResult(np.asarray(best), np.asarray(final),
-                           np.asarray(it))
+    return batch.run("dual", caps, dems, **kw)
 
 
-def solve_dual_demgrad_batch(caps, dems, *, n_valid=None, iters: int = 800,
-                             lr: float = 0.08, tol: float = 0.0,
-                             check_every: int = 25, use_pallas: bool = False,
-                             interpret: bool | None = None,
-                             backend: str | None = None, aot=None,
-                             sharding=None, donate: bool = False,
-                             block: bool = True, d_max: int | None = None,
-                             mean_degree: float | None = None,
-                             max_rounds: int | None = None
-                             ) -> DualDemgradBatchResult:
+def solve_dual_demgrad_batch(caps, dems, **kw) -> DualDemgradBatchResult:
     """``solve_dual_batch`` + per-instance demand gradients — the
     adversarial-traffic search's inner solve.
 
@@ -569,38 +412,4 @@ def solve_dual_demgrad_batch(caps, dems, *, n_valid=None, iters: int = 800,
     its demand matrix (see ``DualDemgradBatchResult``).  One extra APSP
     forward per instance, no APSP backward.
     """
-    interpret = kops.resolve_interpret(interpret)
-    backend = normalize_backend(backend, use_pallas)
-    if len(caps) != len(dems):
-        raise ValueError(f"caps ({len(caps)}) and dems ({len(dems)}) "
-                         "must have equal length")
-    if len(caps) == 0:
-        z = np.zeros(0, np.float32)
-        return DualDemgradBatchResult(z, z.copy(), np.zeros(0, np.int32),
-                                      np.zeros((0, 0, 0), np.float32))
-    if not isinstance(caps, (np.ndarray, jax.Array)):
-        caps = np.stack([as_cap(c) for c in caps])
-    if not isinstance(dems, (np.ndarray, jax.Array)):
-        dems = np.stack([np.asarray(d) for d in dems])
-    if n_valid is None:
-        n_valid = np.full(caps.shape[0], caps.shape[1], np.int32)
-    backend, d_max = resolve_backend_density(
-        backend, caps, n=caps.shape[1], d_max=d_max,
-        mean_degree=mean_degree)
-    capj = jnp.asarray(caps, jnp.float32)
-    demj = jnp.asarray(dems, jnp.float32)
-    nvj = jnp.asarray(n_valid, jnp.int32)
-    if sharding is not None:
-        capj, demj, nvj = jax.device_put((capj, demj, nvj), sharding)
-    fn = _solve_demgrad_batch_donated if donate else _solve_demgrad_batch
-    args = (capj, demj, nvj, jnp.float32(lr), jnp.float32(tol))
-    static_kw = dict(iters=iters, check_every=check_every,
-                     backend=backend, interpret=interpret,
-                     d_max=d_max, max_rounds=max_rounds)
-    best, final, it, g = aotcache.dispatch(
-        fn, ("dual-demgrad", "donated" if donate else "plain"), args,
-        static_kw, aot=aot, sharding=sharding)
-    if not block:
-        return DualDemgradBatchResult(best, final, it, g)
-    return DualDemgradBatchResult(np.asarray(best), np.asarray(final),
-                                  np.asarray(it), np.asarray(g))
+    return batch.run("dual-demgrad", caps, dems, **kw)

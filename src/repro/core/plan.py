@@ -51,7 +51,7 @@ from typing import Any, Mapping, Sequence
 
 import numpy as np
 
-from repro.core import aotcache, mcf, primal, routing, spans
+from repro.core import aotcache, batch, mcf, primal, routing, spans
 from repro.core.graphs import Topology, as_cap, degree_stats
 
 __all__ = ["bucket_size", "device_count", "compile_cache_sizes", "Chunk",
@@ -137,51 +137,22 @@ class InstanceSolve:
     meta: Mapping[str, Any]
 
 
-def _dispatch_dual(capp, demp, n_valid, sharding, solver_kw):
-    r = mcf.solve_dual_batch(capp, demp, n_valid=n_valid, sharding=sharding,
-                             donate=True, block=False, **solver_kw)
-    return {"value": r.throughput_ub, "final_ratio": r.final_ratio,
-            "iterations": r.iterations}
-
-
-def _dispatch_primal(capp, demp, n_valid, sharding, solver_kw):
-    r = primal.solve_primal_batch(capp, demp, n_valid=n_valid,
-                                  sharding=sharding, donate=True,
-                                  block=False, **solver_kw)
-    return {"value": r.throughput_lb, "ub": r.throughput_ub,
-            "final_util": r.final_util, "iterations": r.iterations}
-
-
-def _dispatch_dual_demgrad(capp, demp, n_valid, sharding, solver_kw):
-    r = mcf.solve_dual_demgrad_batch(capp, demp, n_valid=n_valid,
-                                     sharding=sharding, donate=True,
-                                     block=False, **solver_kw)
-    return {"value": r.throughput_ub, "final_ratio": r.final_ratio,
-            "iterations": r.iterations, "dem_grad": r.dem_grad}
-
-
-def _dispatch_ecmp(capp, demp, n_valid, sharding, solver_kw):
-    r = routing.solve_ecmp_batch(capp, demp, n_valid=n_valid,
-                                 sharding=sharding, donate=True,
-                                 block=False, **solver_kw)
-    return {"value": r.throughput_lb, "ub": r.throughput_ub,
-            "final_util": r.final_util, "iterations": r.iterations}
-
-
-def _dispatch_ksp(capp, demp, n_valid, sharding, solver_kw):
-    r = routing.solve_ksp_batch(capp, demp, n_valid=n_valid,
-                                sharding=sharding, donate=True,
-                                block=False, **solver_kw)
-    return {"value": r.throughput_lb, "ub": r.throughput_ub,
-            "final_util": r.final_util, "iterations": r.iterations}
+def _dispatcher(spec: batch.BatchSolver):
+    def dispatch(capp, demp, n_valid, sharding, solver_kw):
+        r = batch.run(spec.name, capp, demp, n_valid=n_valid,
+                      sharding=sharding, donate=True, block=False,
+                      **solver_kw)
+        return {"value" if f.name == spec.value
+                else f.name.removeprefix("throughput_"): getattr(r, f.name)
+                for f in dataclasses.fields(r)}
+    return dispatch
 
 
 # chunk dispatchers by solver name: (capp, demp, n_valid, sharding,
 # solver_kw) -> dict of in-flight per-lane arrays; "value" is the headline
-# bound, every other key is copied into the per-instance meta
-SOLVERS = {"dual": _dispatch_dual, "primal": _dispatch_primal,
-           "dual-demgrad": _dispatch_dual_demgrad,
-           "ecmp": _dispatch_ecmp, "ksp": _dispatch_ksp}
+# bound, every other key is copied into the per-instance meta (a result's
+# other bound, ``throughput_ub``, as "ub")
+SOLVERS = {name: _dispatcher(spec) for name, spec in batch.SOLVERS.items()}
 
 
 def compile_cache_sizes() -> dict[str, int | None]:
@@ -192,11 +163,11 @@ def compile_cache_sizes() -> dict[str, int | None]:
     the persistent AOT cache counters (``aot.compiles`` / ``aot.hits``,
     always-present ints — zero when the cache is off) so warm-run checks
     can assert "no new XLA compiles" across processes."""
-    out: dict[str, int | None] = {}
-    for name, mod in (("dual", mcf), ("primal", primal),
-                      ("routing", routing)):
-        for k, v in mod.compile_cache_sizes().items():
-            out[f"{name}.{k}"] = v
+    size = batch.jit_cache_size
+    out = {"dual.solve": size(mcf._solve), "primal.solve": size(primal._solve),
+           "routing.ecmp": size(routing._ecmp),
+           "routing.ksp": size(routing._ksp),
+           **batch.compile_cache_sizes()}
     a = aotcache.stats()
     out["aot.compiles"] = a["compiles"]
     out["aot.hits"] = a["hits"]
@@ -347,9 +318,9 @@ class BatchPlan:
         "dual", "primal", "dual-demgrad" — the latter additionally
         returns each lane's demand gradient in ``meta["dem_grad"]`` —
         or the routing-restricted "ecmp" / "ksp" lower-bound programs);
-        ``solver_kw`` goes to its ``solve_*_batch``
-        (iters/lr/tol/check_every/use_pallas/interpret/backend/d_max/
-        max_rounds).  When the backend can land on ``"ell-bf"`` and the
+        ``solver_kw`` goes to ``batch.run``
+        (iters/lr/tol/check_every/interpret/backend/d_max/max_rounds and
+        the routing solvers' own).  When the backend can land on ``"ell-bf"`` and the
         caller gave no explicit table stats, each chunk gets density hints
         computed from its own unpadded members (``_density_hints``)."""
         import jax
@@ -360,7 +331,6 @@ class BatchPlan:
                              f"known: {sorted(SOLVERS)}") from None
         sharding = self._sharding()
         want_hints = (solver_kw.get("backend") in (None, "auto", "ell-bf")
-                      and not solver_kw.get("use_pallas")
                       and "d_max" not in solver_kw
                       and "mean_degree" not in solver_kw)
         pending = []

@@ -54,16 +54,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import aotcache
-from repro.core.apsp import normalize_backend
+from repro.core import batch
+from repro.core.apsp import normalize_backend, resolve_backend_density
 from repro.core.graphs import Topology, as_cap
-from repro.core.mcf import (_INF, apsp, jit_cache_size,
-                            resolve_backend_density)
+from repro.core.mcf import _INF, apsp
 from repro.core.spans import scoped
 from repro.kernels import ops as kops
 
 __all__ = ["PrimalResult", "PrimalBatchResult", "solve_primal",
-           "solve_primal_batch", "compile_cache_sizes"]
+           "solve_primal_batch"]
 
 _LS_STEPS = 24   # ternary-search iterations: (2/3)^24 ~ 6e-5 gamma resolution
 
@@ -213,14 +212,7 @@ def _solve_one(cap: jax.Array, dem: jax.Array, n_valid: jax.Array,
     return best_lb, best_ub, umax_of(loads), it
 
 
-# compile-key statics, kept identical to the dual solver's so primal and
-# dual lanes share one AOT-cache key scheme (d_max/max_rounds are the
-# ell-bf table width and relaxation-round cap)
-_STATIC = ("iters", "check_every", "backend", "interpret", "d_max",
-           "max_rounds")
-
-
-@functools.partial(jax.jit, static_argnames=_STATIC)
+@functools.partial(jax.jit, static_argnames=batch._STATIC)
 def _solve(cap, dem, n_valid, lr_peak, tol, *, iters, check_every,
            backend, interpret, d_max=None, max_rounds=None):
     return _solve_one(cap, dem, n_valid, lr_peak, tol, iters=iters,
@@ -229,33 +221,9 @@ def _solve(cap, dem, n_valid, lr_peak, tol, *, iters, check_every,
                       max_rounds=max_rounds)
 
 
-def _solve_batch_impl(caps, dems, n_valid, lr_peak, tol, *, iters,
-                      check_every, backend, interpret, d_max=None,
-                      max_rounds=None):
-    fn = functools.partial(_solve_one, iters=iters, check_every=check_every,
-                           backend=backend, interpret=interpret,
-                           d_max=d_max, max_rounds=max_rounds)
-    return jax.vmap(fn, in_axes=(0, 0, 0, None, None))(
-        caps, dems, n_valid, lr_peak, tol)
-
-
-_solve_batch = jax.jit(_solve_batch_impl, static_argnames=_STATIC)
-_solve_batch_donated = jax.jit(_solve_batch_impl, static_argnames=_STATIC,
-                               donate_argnums=(0, 1))
-
-
-def compile_cache_sizes() -> dict[str, int | None]:
-    """Compiled program variants per primal entry point (mirrors
-    ``mcf.compile_cache_sizes``; ``None`` = introspection unavailable)."""
-    return {"solve": jit_cache_size(_solve),
-            "solve_batch": jit_cache_size(_solve_batch,
-                                          _solve_batch_donated)}
-
-
 def solve_primal(cap: Topology | np.ndarray, dem: np.ndarray, *,
                  iters: int = 800, lr: float = 0.08, tol: float = 0.0,
-                 check_every: int = 25, use_pallas: bool = False,
-                 interpret: bool | None = None,
+                 check_every: int = 25, interpret: bool | None = None,
                  backend: str | None = None, aot=None,
                  d_max: int | None = None,
                  max_rounds: int | None = None) -> PrimalResult:
@@ -266,15 +234,14 @@ def solve_primal(cap: Topology | np.ndarray, dem: np.ndarray, *,
     units, so the (lb, ub) bracket is around the paper's dimensionless
     per-unit-demand θ*.  ``tol > 0`` stops early once the bracket gap's
     shrinkage per ``check_every``-step window drops below it.  ``backend``
-    picks the APSP backend (``use_pallas=True`` aliases "squaring-pallas");
-    ``aot`` is accepted for parity with the batch entry point and
-    ignored."""
+    picks the APSP backend; ``aot`` is accepted for parity with the batch
+    entry point and ignored."""
     del aot
     interpret = kops.resolve_interpret(interpret)
     cap_host = as_cap(cap)
     backend, d_max = resolve_backend_density(
-        normalize_backend(backend, use_pallas), cap_host,
-        n=cap_host.shape[0], d_max=d_max)
+        normalize_backend(backend), cap_host, n=cap_host.shape[0],
+        d_max=d_max)
     capj = jnp.asarray(cap_host, jnp.float32)
     lb, ub, util, it = _solve(
         capj, jnp.asarray(dem, jnp.float32), jnp.int32(capj.shape[0]),
@@ -284,53 +251,11 @@ def solve_primal(cap: Topology | np.ndarray, dem: np.ndarray, *,
     return PrimalResult(float(lb), float(ub), float(util), int(it))
 
 
-def solve_primal_batch(caps, dems, *, n_valid=None, iters: int = 800,
-                       lr: float = 0.08, tol: float = 0.0,
-                       check_every: int = 25, use_pallas: bool = False,
-                       interpret: bool | None = None,
-                       backend: str | None = None, aot=None,
-                       sharding=None, donate: bool = False,
-                       block: bool = True, d_max: int | None = None,
-                       mean_degree: float | None = None,
-                       max_rounds: int | None = None) -> PrimalBatchResult:
+def solve_primal_batch(caps, dems, **kw) -> PrimalBatchResult:
     """Batched primal solve over stacked [R, N, N] topologies/demands; the
-    call surface mirrors ``mcf.solve_dual_batch`` exactly (``n_valid``
-    padding masks, ``sharding``/``donate``/``block`` for the ``BatchPlan``
-    async path), so primal lanes ride the same buckets/chunks/device
-    sharding as dual lanes.  ``backend``/``aot`` mirror the dual too
-    (APSP backend registry; persistent AOT compile cache)."""
-    interpret = kops.resolve_interpret(interpret)
-    backend = normalize_backend(backend, use_pallas)
-    if len(caps) != len(dems):
-        raise ValueError(f"caps ({len(caps)}) and dems ({len(dems)}) "
-                         "must have equal length")
-    if len(caps) == 0:
-        z = np.zeros(0, np.float32)
-        return PrimalBatchResult(z, z.copy(), z.copy(),
-                                 np.zeros(0, np.int32))
-    if not isinstance(caps, (np.ndarray, jax.Array)):
-        caps = np.stack([as_cap(c) for c in caps])
-    if not isinstance(dems, (np.ndarray, jax.Array)):
-        dems = np.stack([np.asarray(d) for d in dems])
-    if n_valid is None:
-        n_valid = np.full(caps.shape[0], caps.shape[1], np.int32)
-    backend, d_max = resolve_backend_density(
-        backend, caps, n=caps.shape[1], d_max=d_max,
-        mean_degree=mean_degree)
-    capj = jnp.asarray(caps, jnp.float32)
-    demj = jnp.asarray(dems, jnp.float32)
-    nvj = jnp.asarray(n_valid, jnp.int32)
-    if sharding is not None:
-        capj, demj, nvj = jax.device_put((capj, demj, nvj), sharding)
-    fn = _solve_batch_donated if donate else _solve_batch
-    args = (capj, demj, nvj, jnp.float32(lr), jnp.float32(tol))
-    static_kw = dict(iters=iters, check_every=check_every,
-                     backend=backend, interpret=interpret,
-                     d_max=d_max, max_rounds=max_rounds)
-    lb, ub, util, it = aotcache.dispatch(
-        fn, ("primal", "donated" if donate else "plain"), args, static_kw,
-        aot=aot, sharding=sharding)
-    if not block:
-        return PrimalBatchResult(lb, ub, util, it)
-    return PrimalBatchResult(np.asarray(lb), np.asarray(ub),
-                             np.asarray(util), np.asarray(it))
+    keywords are ``repro.core.batch.run``'s, as for
+    ``mcf.solve_dual_batch`` (``n_valid`` padding masks,
+    ``sharding``/``donate``/``block`` for the ``BatchPlan`` async path,
+    ``backend``, ``aot``), so primal lanes ride the same buckets/chunks/
+    device sharding as dual lanes."""
+    return batch.run("primal", caps, dems, **kw)

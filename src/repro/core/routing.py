@@ -59,18 +59,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import aotcache
-from repro.core.apsp import normalize_backend
+from repro.core import batch
+from repro.core.apsp import normalize_backend, resolve_backend_density
 from repro.core.graphs import Topology, as_cap
-from repro.core.mcf import (_INF, _descend, apsp, jit_cache_size,
-                            resolve_backend_density)
+from repro.core.mcf import _INF, _descend, apsp
 from repro.kernels import ops as kops
 from repro.kernels import paths as kpaths
 
 __all__ = ["RoutingResult", "RoutingBatchResult", "solve_ecmp",
            "solve_ecmp_batch", "solve_ksp", "solve_ksp_batch",
-           "path_lp_throughput", "compile_cache_sizes",
-           "DEFAULT_K", "DEFAULT_MAX_HOPS"]
+           "path_lp_throughput", "DEFAULT_K", "DEFAULT_MAX_HOPS"]
 
 DEFAULT_K = 8          # path-set width: Jellyfish's evaluation sweet spot
 DEFAULT_MAX_HOPS = 12  # per-path hop budget for the static path tensor
@@ -279,8 +277,7 @@ def _ksp_one(cap, dem, n_valid, paths, lr_peak, tol, *, iters,
 # compile-key statics: the dual/primal set plus the ECMP propagation
 # depth (``hops``), which is resolved from the padded width only so
 # every chunk of a bucket — and every ``refill`` round — shares keys
-_STATIC = ("iters", "check_every", "backend", "interpret", "d_max",
-           "max_rounds", "hops")
+_STATIC = batch._STATIC + ("hops",)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -299,43 +296,6 @@ def _ksp(cap, dem, n_valid, paths, lr_peak, tol, *, iters, check_every,
                     check_every=check_every, backend=backend,
                     interpret=interpret, d_max=d_max,
                     max_rounds=max_rounds, hops=hops)
-
-
-def _ecmp_batch_impl(caps, dems, n_valid, lr_peak, tol, *, iters,
-                     check_every, backend, interpret, d_max=None,
-                     max_rounds=None, hops=None):
-    fn = functools.partial(_ecmp_one, iters=iters, check_every=check_every,
-                           backend=backend, interpret=interpret,
-                           d_max=d_max, max_rounds=max_rounds, hops=hops)
-    return jax.vmap(fn, in_axes=(0, 0, 0, None, None))(
-        caps, dems, n_valid, lr_peak, tol)
-
-
-def _ksp_batch_impl(caps, dems, n_valid, paths, lr_peak, tol, *, iters,
-                    check_every, backend, interpret, d_max=None,
-                    max_rounds=None, hops=None):
-    fn = functools.partial(_ksp_one, iters=iters, check_every=check_every,
-                           backend=backend, interpret=interpret,
-                           d_max=d_max, max_rounds=max_rounds, hops=hops)
-    return jax.vmap(fn, in_axes=(0, 0, 0, 0, None, None))(
-        caps, dems, n_valid, paths, lr_peak, tol)
-
-
-_ecmp_batch = jax.jit(_ecmp_batch_impl, static_argnames=_STATIC)
-_ecmp_batch_donated = jax.jit(_ecmp_batch_impl, static_argnames=_STATIC,
-                              donate_argnums=(0, 1))
-_ksp_batch = jax.jit(_ksp_batch_impl, static_argnames=_STATIC)
-_ksp_batch_donated = jax.jit(_ksp_batch_impl, static_argnames=_STATIC,
-                             donate_argnums=(0, 1))
-
-
-def compile_cache_sizes() -> dict[str, int | None]:
-    """Compiled program variants per routing entry point (mirrors
-    ``mcf.compile_cache_sizes``; ``None`` = introspection unavailable)."""
-    return {"ecmp": jit_cache_size(_ecmp),
-            "ecmp_batch": jit_cache_size(_ecmp_batch, _ecmp_batch_donated),
-            "ksp": jit_cache_size(_ksp),
-            "ksp_batch": jit_cache_size(_ksp_batch, _ksp_batch_donated)}
 
 
 def _resolve_hops(nmax: int, hops: int | None) -> int:
@@ -375,9 +335,9 @@ def _paths_tensor(caps: np.ndarray, n_valid: np.ndarray, k: int,
 
 def solve_ecmp(cap: Topology | np.ndarray, dem: np.ndarray, *,
                iters: int = 800, lr: float = 0.08, tol: float = 0.0,
-               check_every: int = 25, use_pallas: bool = False,
-               interpret: bool | None = None, backend: str | None = None,
-               aot=None, d_max: int | None = None,
+               check_every: int = 25, interpret: bool | None = None,
+               backend: str | None = None, aot=None,
+               d_max: int | None = None,
                max_rounds: int | None = None,
                hops: int | None = None) -> RoutingResult:
     """Certified ECMP lower bound for one instance (module docstring);
@@ -389,7 +349,7 @@ def solve_ecmp(cap: Topology | np.ndarray, dem: np.ndarray, *,
     cap_host = as_cap(cap)
     n = cap_host.shape[0]
     backend, d_max = resolve_backend_density(
-        normalize_backend(backend, use_pallas), cap_host, n=n, d_max=d_max)
+        normalize_backend(backend), cap_host, n=n, d_max=d_max)
     lb, ub, util, it = _ecmp(
         jnp.asarray(cap_host, jnp.float32), jnp.asarray(dem, jnp.float32),
         jnp.int32(n), jnp.float32(lr), jnp.float32(tol), iters=iters,
@@ -401,9 +361,9 @@ def solve_ecmp(cap: Topology | np.ndarray, dem: np.ndarray, *,
 def solve_ksp(cap: Topology | np.ndarray, dem: np.ndarray, *,
               k: int = DEFAULT_K, max_hops: int | None = None,
               iters: int = 800, lr: float = 0.08, tol: float = 0.0,
-              check_every: int = 25, use_pallas: bool = False,
-              interpret: bool | None = None, backend: str | None = None,
-              aot=None, d_max: int | None = None,
+              check_every: int = 25, interpret: bool | None = None,
+              backend: str | None = None, aot=None,
+              d_max: int | None = None,
               max_rounds: int | None = None,
               hops: int | None = None) -> RoutingResult:
     """Certified k-shortest-path lower bound for one instance (module
@@ -414,7 +374,7 @@ def solve_ksp(cap: Topology | np.ndarray, dem: np.ndarray, *,
     cap_host = as_cap(cap)
     n = cap_host.shape[0]
     backend, d_max = resolve_backend_density(
-        normalize_backend(backend, use_pallas), cap_host, n=n, d_max=d_max)
+        normalize_backend(backend), cap_host, n=n, d_max=d_max)
     mh = _resolve_max_hops(n, max_hops)
     paths = _paths_tensor(cap_host[None], np.full(1, n, np.int32), k, mh)[0]
     lb, ub, util, it = _ksp(
@@ -426,110 +386,35 @@ def solve_ksp(cap: Topology | np.ndarray, dem: np.ndarray, *,
     return RoutingResult(float(lb), float(ub), float(util), int(it))
 
 
-def _prep_batch(caps, dems, n_valid, backend, use_pallas, d_max,
-                mean_degree):
-    if len(caps) != len(dems):
-        raise ValueError(f"caps ({len(caps)}) and dems ({len(dems)}) "
-                         "must have equal length")
-    if not isinstance(caps, (np.ndarray, jax.Array)):
-        caps = np.stack([as_cap(c) for c in caps])
-    if not isinstance(dems, (np.ndarray, jax.Array)):
-        dems = np.stack([np.asarray(d) for d in dems])
-    if n_valid is None:
-        n_valid = np.full(caps.shape[0], caps.shape[1], np.int32)
-    backend, d_max = resolve_backend_density(
-        normalize_backend(backend, use_pallas), caps, n=caps.shape[1],
-        d_max=d_max, mean_degree=mean_degree)
-    return caps, dems, np.asarray(n_valid, np.int32), backend, d_max
+def _ecmp_extra(caps, n_valid, *, hops: int | None = None):
+    """ECMP's batch hook (``repro.core.batch``): its propagation depth."""
+    return (), {"hops": _resolve_hops(caps.shape[1], hops)}
 
 
-def _empty_batch() -> RoutingBatchResult:
-    z = np.zeros(0, np.float32)
-    return RoutingBatchResult(z, z.copy(), z.copy(), np.zeros(0, np.int32))
-
-
-def solve_ecmp_batch(caps, dems, *, n_valid=None, iters: int = 800,
-                     lr: float = 0.08, tol: float = 0.0,
-                     check_every: int = 25, use_pallas: bool = False,
-                     interpret: bool | None = None,
-                     backend: str | None = None, aot=None, sharding=None,
-                     donate: bool = False, block: bool = True,
-                     d_max: int | None = None,
-                     mean_degree: float | None = None,
-                     max_rounds: int | None = None,
-                     hops: int | None = None) -> RoutingBatchResult:
-    """Batched ECMP solve over stacked [R, N, N] topologies/demands; the
-    call surface mirrors ``mcf.solve_dual_batch`` exactly (``n_valid``
-    padding masks, ``sharding``/``donate``/``block`` for the
-    ``BatchPlan`` async path, ``aot`` persistent compile cache)."""
-    interpret = kops.resolve_interpret(interpret)
-    if len(caps) == 0:
-        return _empty_batch()
-    caps, dems, n_valid, backend, d_max = _prep_batch(
-        caps, dems, n_valid, backend, use_pallas, d_max, mean_degree)
-    capj = jnp.asarray(caps, jnp.float32)
-    demj = jnp.asarray(dems, jnp.float32)
-    nvj = jnp.asarray(n_valid, jnp.int32)
-    if sharding is not None:
-        capj, demj, nvj = jax.device_put((capj, demj, nvj), sharding)
-    fn = _ecmp_batch_donated if donate else _ecmp_batch
-    args = (capj, demj, nvj, jnp.float32(lr), jnp.float32(tol))
-    static_kw = dict(iters=iters, check_every=check_every, backend=backend,
-                     interpret=interpret, d_max=d_max,
-                     max_rounds=max_rounds,
-                     hops=_resolve_hops(caps.shape[1], hops))
-    lb, ub, util, it = aotcache.dispatch(
-        fn, ("ecmp", "donated" if donate else "plain"), args, static_kw,
-        aot=aot, sharding=sharding)
-    if not block:
-        return RoutingBatchResult(lb, ub, util, it)
-    return RoutingBatchResult(np.asarray(lb), np.asarray(ub),
-                              np.asarray(util), np.asarray(it))
-
-
-def solve_ksp_batch(caps, dems, *, n_valid=None, k: int = DEFAULT_K,
-                    max_hops: int | None = None, iters: int = 800,
-                    lr: float = 0.08, tol: float = 0.0,
-                    check_every: int = 25, use_pallas: bool = False,
-                    interpret: bool | None = None,
-                    backend: str | None = None, aot=None, sharding=None,
-                    donate: bool = False, block: bool = True,
-                    d_max: int | None = None,
-                    mean_degree: float | None = None,
-                    max_rounds: int | None = None,
-                    hops: int | None = None) -> RoutingBatchResult:
-    """Batched KSP solve; surface = ``solve_ecmp_batch`` plus the path
-    knobs ``k`` (paths per pair) and ``max_hops`` (per-path hop budget,
-    default min(N - 1, DEFAULT_MAX_HOPS) — resolved from the padded
-    width only, so refill rounds share compile keys).  Path tensors are
-    enumerated host-side per lane (deduped across identical lanes)."""
-    interpret = kops.resolve_interpret(interpret)
-    if len(caps) == 0:
-        return _empty_batch()
-    caps, dems, n_valid, backend, d_max = _prep_batch(
-        caps, dems, n_valid, backend, use_pallas, d_max, mean_degree)
+def _ksp_extra(caps, n_valid, *, k: int = DEFAULT_K,
+               max_hops: int | None = None, hops: int | None = None):
+    """KSP's batch hook: each lane's path tensor, and ``hops``."""
     mh = _resolve_max_hops(caps.shape[1], max_hops)
-    paths = _paths_tensor(np.asarray(caps), n_valid, k, mh)
-    capj = jnp.asarray(caps, jnp.float32)
-    demj = jnp.asarray(dems, jnp.float32)
-    nvj = jnp.asarray(n_valid, jnp.int32)
-    pj = jnp.asarray(paths)
-    if sharding is not None:
-        capj, demj, nvj, pj = jax.device_put((capj, demj, nvj, pj),
-                                             sharding)
-    fn = _ksp_batch_donated if donate else _ksp_batch
-    args = (capj, demj, nvj, pj, jnp.float32(lr), jnp.float32(tol))
-    static_kw = dict(iters=iters, check_every=check_every, backend=backend,
-                     interpret=interpret, d_max=d_max,
-                     max_rounds=max_rounds,
-                     hops=_resolve_hops(caps.shape[1], hops))
-    lb, ub, util, it = aotcache.dispatch(
-        fn, ("ksp", "donated" if donate else "plain"), args, static_kw,
-        aot=aot, sharding=sharding)
-    if not block:
-        return RoutingBatchResult(lb, ub, util, it)
-    return RoutingBatchResult(np.asarray(lb), np.asarray(ub),
-                              np.asarray(util), np.asarray(it))
+    return ((_paths_tensor(caps, n_valid, k, mh),),
+            {"hops": _resolve_hops(caps.shape[1], hops)})
+
+
+def solve_ecmp_batch(caps, dems, **kw) -> RoutingBatchResult:
+    """Batched ECMP solve over stacked [R, N, N] topologies/demands; the
+    keywords are ``repro.core.batch.run``'s, as for
+    ``mcf.solve_dual_batch``, plus ``hops`` (the fixed-point depth,
+    default the padded width)."""
+    return batch.run("ecmp", caps, dems, **kw)
+
+
+def solve_ksp_batch(caps, dems, **kw) -> RoutingBatchResult:
+    """Batched KSP solve; keywords = ``solve_ecmp_batch``'s plus the path
+    knobs ``k`` (paths per pair, default ``DEFAULT_K``) and ``max_hops``
+    (per-path hop budget, default min(N - 1, DEFAULT_MAX_HOPS) —
+    resolved from the padded width only, so refill rounds share compile
+    keys).  Path tensors are enumerated host-side per lane (deduped
+    across identical lanes)."""
+    return batch.run("ksp", caps, dems, **kw)
 
 
 def path_lp_throughput(cap: Topology | np.ndarray, dem: np.ndarray,

@@ -141,7 +141,7 @@ def optimize(space: DesignSpace,
     (default: a random server permutation); every candidate is scored on
     the same ``runs`` fixed seeds so ranking is apples-to-apples across
     rounds.  ``engine`` must be a planning engine (``"dual"`` /
-    ``"dual-pallas"`` / ``"primal"`` / ``"certified"`` or a
+    ``"primal"`` / ``"certified"`` or a
     ``_PlannedEngine`` instance — the search NEEDS ``BatchPlan``; default:
     a ``DualEngine(iters=250, tol=1e-3)`` tuned for cheap ranking).
     ``moves`` names kernels from ``repro.design.moves.MOVES``.  Kernels
@@ -182,8 +182,8 @@ def optimize(space: DesignSpace,
     if not isinstance(eng, _PlannedEngine):
         raise ValueError(
             f"engine {getattr(eng, 'name', eng)!r} does not execute through "
-            "a BatchPlan; the designer needs one of dual/dual-pallas/"
-            "primal/certified (exact LP ranking would solve the fleet "
+            "a BatchPlan; the designer needs one of dual/primal/"
+            "certified (exact LP ranking would solve the fleet "
             "sequentially)")
 
     executes = 0

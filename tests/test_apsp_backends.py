@@ -485,11 +485,11 @@ def test_fw_pallas_validates_shapes():
 # ---------------------------------------------------------------------------
 
 def test_normalize_backend_mapping():
-    assert normalize_backend(None, use_pallas=False) == "auto"
-    assert normalize_backend(None, use_pallas=True) == "squaring-pallas"
-    assert normalize_backend(True) == "squaring-pallas"    # legacy bool slot
-    assert normalize_backend(False) == "squaring"
+    assert normalize_backend(None) == "auto"
+    assert normalize_backend("squaring-pallas") == "squaring-pallas"
     assert normalize_backend("blocked-fw") == "blocked-fw"
+    with pytest.raises(ValueError, match="unknown APSP backend"):
+        normalize_backend(True)    # no bool alias for a backend
     with pytest.raises(ValueError, match="unknown APSP backend"):
         normalize_backend("dijkstra")
 

@@ -4,10 +4,12 @@ Covers: bucket-size selection, padded-vs-unpadded equivalence on mixed-size
 batches, convergence-based early stopping (single and batched), per-instance
 batch meta, and the interpret-mode auto-detection plumbing.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
-from repro.core import graphs, mcf, traffic
+from repro.core import graphs, mcf, plan, primal, routing, traffic
 from repro.core.engine import DualEngine, bucket_size
 from repro.kernels import ops
 
@@ -130,13 +132,38 @@ def test_solve_batch_meta_matches_solver_outputs():
             single.meta["final_ratio"], rel=1e-3)
 
 
-def test_empty_batch_returns_empty():
-    # regression: np.stack([]) used to blow up with an opaque error
-    empty = mcf.solve_dual_batch([], [])
-    assert isinstance(empty, mcf.DualBatchResult)
-    assert len(empty) == 0 and list(empty) == []
-    assert empty.iterations.shape == (0,)
-    assert DualEngine(iters=50).solve_batch([], []) == []
+_BATCH_ENTRIES = {
+    "dual": (mcf.solve_dual_batch, mcf.DualBatchResult),
+    "dual-demgrad": (mcf.solve_dual_demgrad_batch,
+                     mcf.DualDemgradBatchResult),
+    "primal": (primal.solve_primal_batch, primal.PrimalBatchResult),
+    "ecmp": (routing.solve_ecmp_batch, routing.RoutingBatchResult),
+    "ksp": (routing.solve_ksp_batch, routing.RoutingBatchResult),
+}
+
+
+@pytest.mark.parametrize("solver", sorted(plan.SOLVERS))
+def test_empty_batch_returns_empty(solver):
+    """Every batch entry: an empty batch gives an empty result of its own
+    type (regression: np.stack([]) used to blow up with an opaque error),
+    a length mismatch raises, and ``n_valid`` defaults to the full
+    width."""
+    entry, result_type = _BATCH_ENTRIES[solver]
+    empty = entry([], [])
+    assert type(empty) is result_type
+    assert len(empty) == 0 and empty.iterations.shape == (0,)
+    insts = [_instance(8, s, r=3) for s in range(2)]
+    caps = [t.cap for t, _ in insts]
+    dems = [d for _, d in insts]
+    with pytest.raises(ValueError, match="equal length"):
+        entry(caps, dems[:1])
+    default = entry(caps, dems, iters=30)
+    full = entry(caps, dems, n_valid=np.full(2, 8), iters=30)
+    assert type(default) is result_type and len(default) == 2
+    for a, b in zip(dataclasses.astuple(default), dataclasses.astuple(full)):
+        np.testing.assert_array_equal(a, b)
+    if solver == "dual":
+        assert DualEngine(iters=50).solve_batch([], []) == []
 
 
 def test_batch_length_mismatch_raises():
@@ -171,10 +198,10 @@ def test_resolve_interpret():
 
 
 def test_dual_pallas_interpret_threads_through_engine():
-    # explicit interpret=True must work on any backend; use_pallas on a
-    # small instance exercises the ref fallback inside ops.minplus_matmul
+    # explicit interpret=True must work on any backend; squaring-pallas on
+    # a small instance exercises the ref fallback inside ops.minplus_matmul
     topo, dem = _instance(16, 1)
-    eng = DualEngine(use_pallas=True, interpret=True, iters=150)
+    eng = DualEngine(backend="squaring-pallas", interpret=True, iters=150)
     plain = DualEngine(iters=150)
     a = eng.solve(topo, dem)
     b = plain.solve(topo, dem)

@@ -95,14 +95,6 @@ def test_primal_unroutable_demand_reports_zero_lb():
     assert res.throughput_ub < 1e-6, "dual agrees theta* = 0"
 
 
-def test_primal_batch_empty_and_mismatch():
-    empty = primal.solve_primal_batch([], [])
-    assert isinstance(empty, primal.PrimalBatchResult)
-    assert len(empty) == 0 and list(empty) == []
-    with pytest.raises(ValueError, match="equal length"):
-        primal.solve_primal_batch([np.eye(4)], [])
-
-
 # ---------------------------------------------------------------------------
 # BatchPlan primal path
 # ---------------------------------------------------------------------------
